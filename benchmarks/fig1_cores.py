@@ -35,7 +35,8 @@ def run(quick: bool = False) -> str:
                                     "energy_j": energy})
         rows.append(["host (measured)", str(c), wall, energy])
 
-    lines = ["# Fig. 1 — one container, varying CPU cores", ""]
+    lines = ["# Fig. 1 — one container, varying CPU cores (CPU wall times)",
+             ""]
     lines += table(["device", "cores", "time (s)", "energy (J)"], rows)
     t1 = payload["measured"][0]["time_s"]
     t8 = payload["measured"][-1]["time_s"]
@@ -45,4 +46,6 @@ def run(quick: bool = False) -> str:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     print(run())

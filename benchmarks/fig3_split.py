@@ -63,8 +63,8 @@ def run(quick: bool = False) -> str:
                           r.energy_j / base.energy_j,
                           r.avg_power_w / base.avg_power_w,
                           "✓" if ok else "✗"])
-    lines += ["", f"## Host testbed (REAL wall times, {total_cores} cores, "
-              f"{n_frames} frames)", ""]
+    lines += ["", f"## Host CPU testbed (real CPU wall times, {total_cores} "
+              f"cores, {n_frames} frames)", ""]
     lines += table(["n", "time (norm)", "energy (norm)", "power (norm)",
                     "outputs=="], meas_rows)
 
@@ -94,4 +94,6 @@ def run(quick: bool = False) -> str:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     print(run())

@@ -187,6 +187,8 @@ def run(quick: bool = False) -> str:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

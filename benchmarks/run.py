@@ -6,6 +6,7 @@ the TPU adaptation sweep.
 from __future__ import annotations
 
 import argparse
+import os
 import subprocess
 import sys
 import time
@@ -18,6 +19,8 @@ def main() -> int:
                     help="skip the (slower) pod-factorisation sweep")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (decode_throughput, fig1_cores, fig3_split,
                             pool_scaling, table2_fit)
 
@@ -64,13 +67,18 @@ def main() -> int:
         for arch, shape in sweeps:
             print("=" * 72)
             print(f"tpu_split — divide-and-save on the 256-chip pod: "
-                  f"{arch} × {shape} (subprocess: 512-device override)")
+                  f"{arch} × {shape} (CPU subprocess: lowered over 512 "
+                  "fake CPU devices; roofline estimates, not chip "
+                  "measurements)")
             print("=" * 72)
             cmd = [sys.executable, "-m", "benchmarks.tpu_split",
                    "--arch", arch, "--shape", shape]
             if args.quick:
                 cmd.append("--quick")
-            r = subprocess.run(cmd)
+            # a CPU-only child by design: it must never contend for a
+            # chip this parent may hold
+            r = subprocess.run(cmd, env={**os.environ,
+                                         "JAX_PLATFORMS": "cpu"})
             if r.returncode != 0:
                 print("tpu_split FAILED")
                 return 1

@@ -65,7 +65,7 @@ def run(quick: bool = False) -> str:
     payload["host.energy"] = {"kind": e_fit.kind, "coef": list(e_fit.coef),
                               "rmse": e_fit.rmse,
                               "argmin": e_fit.argmin(8), "samples": meas_e}
-    lines += ["", "## Host testbed fits (real wall times)", ""]
+    lines += ["", "## Host CPU testbed fits (real CPU wall times)", ""]
     lines += table(
         ["metric", "fit", "coef", "rmse", "argmin n"],
         [["time", t_fit.kind, ", ".join(f"{c:.3f}" for c in t_fit.coef),
@@ -76,4 +76,6 @@ def run(quick: bool = False) -> str:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     print(run())

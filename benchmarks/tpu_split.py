@@ -99,7 +99,8 @@ def run(arch: str = "qwen3-8b", shape: str = "decode_32k",
              p["weight_gb_per_chip"]] for p in points]
     lines = [f"# Divide-and-save on the pod — {arch} × {shape}",
              "", "Normalised to the n=1 (whole-pod single container) "
-             "benchmark.", ""]
+             "benchmark. Roofline estimates from a lowering on 512 "
+             "fake CPU devices: CPU-derived, not chip measurements.", ""]
     lines += table(["n", "chips/ctr", "feasible", "step (norm)",
                     "energy (norm)", "dominant", "weights GB/chip"], rows)
 
@@ -123,6 +124,8 @@ def run(arch: str = "qwen3-8b", shape: str = "decode_32k",
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-8b")
     ap.add_argument("--shape", default="decode_32k")

@@ -10,6 +10,9 @@ shared jit cache. The captured spec is then checked:
 * block-shape divisibility — every BlockSpec dim must divide its
   operand dim (our kernels tile exactly; a non-dividing block means
   silent padding or a runtime error on the accelerator);
+* TPU tiling — the last two block dims must be multiples of (8, 128)
+  or equal the operand's own dims, the rule the TPU lowering enforces
+  (a ``(1, block_k)`` mask block over a ``(B, W)`` mask is refused);
 * index-map bounds — each index map is evaluated at every grid corner
   with worst-case scalar-prefetch values (block tables filled with the
   LAST physical page) and must keep ``(idx+1)·block ≤ shape``;
@@ -154,6 +157,16 @@ def check_spec(spec: KernelSpec,
                     "kernels", "KRN002", f"{site}/{way}{k}",
                     f"block dim {d} = {b} does not tile operand dim "
                     f"{s} exactly ({block} vs {shape})"))
+        for d, align in zip(range(len(block) - 2, len(block)), (8, 128)):
+            if d < 0:
+                continue
+            b = shape[d] if block[d] is None else block[d]
+            if b != shape[d] and b % align:
+                findings.append(Finding(
+                    "kernels", "KRN011", f"{site}/{way}{k}",
+                    f"block dim {d} = {b} is neither a multiple of {align} "
+                    f"nor the operand dim {shape[d]} ({block} vs {shape}) "
+                    "— the TPU lowering refuses it"))
         for corner in _grid_corners(spec.grid):
             try:
                 idx = bspec.index_map(*corner, *prefetch)
@@ -212,10 +225,13 @@ def check_spec(spec: KernelSpec,
     int8_ops = [i for i, av in enumerate(spec.operands)
                 if np.dtype(av.dtype) == np.int8]
     if int8_ops:
+        # one f32 scale per int8 row: the int8 operand's size over its
+        # last (head) dim, in whatever layout the kernel reads it
+        q8 = spec.operands[int8_ops[0]].shape
+        rows = int(np.prod(q8[:-1]))
         scales = [av for av in spec.operands
                   if np.dtype(av.dtype) == np.float32
-                  and len(av.shape) == len(
-                      spec.operands[int8_ops[0]].shape) - 1]
+                  and int(np.prod(av.shape)) == rows]
         if int8_scales_expected and not scales:
             findings.append(Finding(
                 "kernels", "KRN008", site,
@@ -257,6 +273,8 @@ def _invoke(name: str, fn: Callable, args: tuple,
 
 
 def run() -> list[Finding]:
+    from repro.kernels.decode_attention import (decode_attention,
+                                                decode_attention_int8)
     from repro.kernels.flash_attention import flash_attention
     from repro.kernels.mla_decode import mla_decode_ctx
     from repro.kernels.paged_attention import (paged_decode_attention,
@@ -272,6 +290,17 @@ def run() -> list[Finding]:
          (_f32(1, 256, 4, 128), _f32(1, 256, 2, 128), _f32(1, 256, 2, 128)),
          dict(causal=True, window=0, softcap=0.0,
               block_q=128, block_k=128, interpret=False), {}, False),
+        ("decode_attention", decode_attention,
+         (_f32(2, 4, 128), _f32(2, 512, 2, 128), _f32(2, 512, 2, 128),
+          jax.ShapeDtypeStruct((2, 512), jnp.bool_)),
+         dict(softcap=0.0, block_k=256, interpret=False), {}, False),
+        ("decode_attention_int8", decode_attention_int8,
+         (_f32(2, 4, 128),
+          jax.ShapeDtypeStruct((2, 512, 2, 128), jnp.int8),
+          jax.ShapeDtypeStruct((2, 512, 2, 128), jnp.int8),
+          jax.ShapeDtypeStruct((2, 512), jnp.bool_),
+          _f32(2, 512, 2), _f32(2, 512, 2)),
+         dict(softcap=0.0, block_k=256, interpret=False), {}, True),
         ("paged_decode_attention", paged_decode_attention,
          (_f32(2, 4, 128),
           _f32(P, bs, 2, 128), _f32(P, bs, 2, 128),

@@ -1,6 +1,6 @@
-"""Qwen3-0.6B [hf:Qwen/Qwen3-8B family] — dense, GQA kv=8, qk_norm.
+"""Qwen3-0.6B [hf:Qwen/Qwen3-0.6B config.json] — dense, GQA kv=8, qk_norm.
 
-Per the model card head_dim is 128 even though 16*128 != d_model (q/k/v
+Per its config.json head_dim is 128 even though 16*128 != d_model (q/k/v
 projections are rectangular); we keep that faithful.
 """
 from repro.configs.base import ArchConfig
@@ -8,7 +8,7 @@ from repro.configs.base import ArchConfig
 CONFIG = ArchConfig(
     name="qwen3-0.6b",
     arch_type="dense",
-    source="hf:Qwen/Qwen3-8B (0.6B sibling)",
+    source="hf:Qwen/Qwen3-0.6B config.json",
     n_layers=28,
     d_model=1024,
     n_heads=16,

@@ -10,8 +10,10 @@ shapes are per-chip, so pod totals are parser × chips and the terms reduce
 to per-chip figures over per-chip bandwidths — identical algebra, stated
 both ways in the report.
 
-Hardware model (TPU v5e-class, per assignment):
-  197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: one TPU v5e chip (``DEVICE_KIND``), published peaks
+(Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s HBM;
+~50 GB/s/link ICI. The constants describe that device only: a run on
+another device kind must not price itself with them (``check_device_kind``).
 """
 from __future__ import annotations
 
@@ -21,6 +23,7 @@ import math
 from repro.configs.base import ArchConfig, InputShape
 from repro.core.hlo_analysis import HloCost
 
+DEVICE_KIND = "TPU v5 lite"   # jax's device_kind for a v5e chip
 PEAK_FLOPS = 197e12        # bf16 / chip
 HBM_BW = 819e9             # bytes/s / chip
 ICI_BW = 50e9              # bytes/s / link (one effective link per phase)
@@ -33,6 +36,15 @@ P_PEAK_W = 350.0
 # scheduler bookkeeping) — the per-token overhead the fused chunk decode
 # amortises; edge-class hosts sit around 10⁻⁴ s
 DISPATCH_OVERHEAD_S = 1e-4
+
+
+def check_device_kind(kind: str) -> None:
+    """Raise unless the peak constants above describe ``kind`` (a
+    ``jax.Device.device_kind``)."""
+    if kind != DEVICE_KIND:
+        raise ValueError(f"roofline peaks describe {DEVICE_KIND!r}, not "
+                         f"{kind!r}: add that device's published peaks "
+                         "before pricing work on it")
 
 
 @dataclasses.dataclass

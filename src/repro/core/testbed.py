@@ -138,9 +138,15 @@ def spawn_pinned(body: Callable, cores: Sequence[int], args: tuple = (),
 def _detector_body(conn, go, frames, batch):
     """Container body. Affinity was set by the harness; jax import here
     (threadpool size follows the cpuset), then warmup, then the timed
-    frame loop."""
+    frame loop. The paper's experiment divides CPU cores, so the child
+    asks for the CPU platform itself: on an accelerator host it must not
+    contend for a device the parent may hold. The config update, not
+    only the variable, because a spawned child re-imports the parent's
+    main module, which may have imported jax already."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
     import jax.numpy as jnp
+    jax.config.update("jax_platforms", "cpu")
 
     def init(key):
         params = []

@@ -23,8 +23,18 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale: float, softcap: float):
+def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, *rest, scale: float,
+                   softcap: float, quantized: bool):
+    """One (batch, kv_head, kv-tile) step. ``quantized`` adds two scale
+    refs after ``valid_ref``: int8 k/v tiles are dequantised in VMEM
+    (per-token, per-head absmax scales), so HBM traffic is the int8
+    bytes plus the scales. A token's k scale multiplies its score column
+    and its v scale its probability column, which keeps the scales in
+    the lane layout they arrive in."""
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -36,18 +46,23 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
     q = q_ref[0, 0, :, :].astype(jnp.float32)      # (G, K)
     k = k_ref[0, 0, :, :].astype(jnp.float32)      # (BK, K)
     v = v_ref[0, 0, :, :].astype(jnp.float32)      # (BK, K)
-    valid = valid_ref[0, :]                        # (BK,)
+    valid = valid_ref[0, :, :]                     # (1, BK)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G, BK)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, BK)
+    if quantized:
+        s = s * ks_ref[0, 0, :, :].astype(jnp.float32)
+    s = s * scale
     if softcap and softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
     l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+    if quantized:
+        p = p * vs_ref[0, 0, :, :].astype(jnp.float32)
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())))
     m_scr[...] = m_new
@@ -58,98 +73,12 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
         o_ref[0, 0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _decode_kernel_int8(q_ref, k_ref, v_ref, valid_ref, ks_ref, vs_ref,
-                        o_ref, m_scr, l_scr, acc_scr, *, scale: float,
-                        softcap: float):
-    """int8-cache variant: k/v tiles are dequantised IN VMEM (per-token,
-    per-head absmax scales) — HBM traffic is the int8 bytes + scales."""
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, 0, :, :].astype(jnp.float32)               # (G, K)
-    ks = ks_ref[0, 0, :].astype(jnp.float32)                # (BK,)
-    vs = vs_ref[0, 0, :].astype(jnp.float32)
-    k = k_ref[0, 0, :, :].astype(jnp.float32) * ks[:, None]
-    v = v_ref[0, 0, :, :].astype(jnp.float32) * vs[:, None]
-    valid = valid_ref[0, :]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    if softcap and softcap > 0.0:
-        s = jnp.tanh(s / softcap) * softcap
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())))
-    m_scr[...] = m_new
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("softcap", "block_k", "interpret"))
-def decode_attention_int8(q: jax.Array, k: jax.Array, v: jax.Array,
-                          valid: jax.Array, k_scale: jax.Array,
-                          v_scale: jax.Array, *, softcap: float = 0.0,
-                          block_k: int = 512,
-                          interpret: bool = False) -> jax.Array:
-    """q: (B,H,K) fp; k/v: (B,W,Hkv,K) int8; scales: (B,W,Hkv) f32."""
-    B, H, K = q.shape
-    W, Hkv = k.shape[1], k.shape[2]
-    G = H // Hkv
-    block_k = min(block_k, W)
-    assert W % block_k == 0, (W, block_k)
-    grid = (B, Hkv, W // block_k)
-
-    qg = q.reshape(B, Hkv, G, K)
-    kt = jnp.moveaxis(k, 2, 1)                              # (B, Hkv, W, K)
-    vt = jnp.moveaxis(v, 2, 1)
-    kst = jnp.moveaxis(k_scale, 2, 1)                       # (B, Hkv, W)
-    vst = jnp.moveaxis(v_scale, 2, 1)
-
-    kernel = functools.partial(_decode_kernel_int8, scale=K ** -0.5,
-                               softcap=softcap)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, G, K), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, K), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, K), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, 1, block_k), lambda b, h, j: (b, h, j)),
-            pl.BlockSpec((1, 1, block_k), lambda b, h, j: (b, h, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, K), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, K), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, K), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qg, kt, vt, valid, kst, vst)
-    return out.reshape(B, H, K)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("softcap", "block_k", "interpret"))
-def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     valid: jax.Array, *, softcap: float = 0.0,
-                     block_k: int = 512, interpret: bool = False) -> jax.Array:
-    """q: (B, H, K); k/v: (B, W, Hkv, K); valid: (B, W) bool -> (B, H, K)."""
+def _decode_call(q, k, v, valid, scales, *, softcap: float, block_k: int,
+                 interpret: bool) -> jax.Array:
+    """Shared pallas_call. The mask rides as ``(B, 1, W)`` and the scales
+    as ``(B, Hkv, 1, W)``, so every block's last two dims are either the
+    array's own or a ``(1, block_k)`` lane tile — the TPU lowering
+    refuses a ``(1, block_k)`` block over a ``(B, W)`` array."""
     B, H, K = q.shape
     W, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
@@ -160,19 +89,24 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qg = q.reshape(B, Hkv, G, K)
     kt = jnp.moveaxis(k, 2, 1)                     # (B, Hkv, W, K)
     vt = jnp.moveaxis(v, 2, 1)
-    valid2 = valid
+    tile = pl.BlockSpec((1, 1, block_k, K), lambda b, h, j: (b, h, j, 0))
+    in_specs = [
+        pl.BlockSpec((1, 1, G, K), lambda b, h, j: (b, h, 0, 0)),
+        tile, tile,
+        pl.BlockSpec((1, 1, block_k), lambda b, h, j: (b, 0, j)),
+    ]
+    args = [qg, kt, vt, valid.reshape(B, 1, W)]
+    for sc in scales:                              # (B, W, Hkv) each
+        in_specs.append(pl.BlockSpec((1, 1, 1, block_k),
+                                     lambda b, h, j: (b, h, 0, j)))
+        args.append(jnp.moveaxis(sc, 2, 1).reshape(B, Hkv, 1, W))
 
     kernel = functools.partial(_decode_kernel, scale=K ** -0.5,
-                               softcap=softcap)
+                               softcap=softcap, quantized=bool(scales))
     out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, G, K), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, K), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, K), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, j: (b, j)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, G, K), lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, K), q.dtype),
         scratch_shapes=[
@@ -181,5 +115,27 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, K), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, kt, vt, valid2)
+    )(*args)
     return out.reshape(B, H, K)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("softcap", "block_k", "interpret"))
+def decode_attention_int8(q: jax.Array, k: jax.Array, v: jax.Array,
+                          valid: jax.Array, k_scale: jax.Array,
+                          v_scale: jax.Array, *, softcap: float = 0.0,
+                          block_k: int = 512,
+                          interpret: bool = False) -> jax.Array:
+    """q: (B,H,K) fp; k/v: (B,W,Hkv,K) int8; scales: (B,W,Hkv) f32."""
+    return _decode_call(q, k, v, valid, (k_scale, v_scale), softcap=softcap,
+                        block_k=block_k, interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("softcap", "block_k", "interpret"))
+def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     valid: jax.Array, *, softcap: float = 0.0,
+                     block_k: int = 512, interpret: bool = False) -> jax.Array:
+    """q: (B, H, K); k/v: (B, W, Hkv, K); valid: (B, W) bool -> (B, H, K)."""
+    return _decode_call(q, k, v, valid, (), softcap=softcap,
+                        block_k=block_k, interpret=interpret)

@@ -38,12 +38,12 @@ def _mla_kernel(ql_ref, qr_ref, ckv_ref, kr_ref, valid_ref, o_ref,
     qr = qr_ref[0].astype(jnp.float32)            # (H, dr)
     ckv = ckv_ref[0].astype(jnp.float32)          # (BS, r)
     kr = kr_ref[0].astype(jnp.float32)            # (BS, dr)
-    valid = valid_ref[0, :]                       # (BS,)
+    valid = valid_ref[0, :, :]                    # (1, BS)
 
     s = jax.lax.dot_general(ql, ckv, (((1,), (1,)), ((), ())))
     s += jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())))
     s *= scale                                    # (H, BS)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -82,7 +82,9 @@ def mla_decode_ctx(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array,
             pl.BlockSpec((1, H, dr), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, block_s, r), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, block_s, dr), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_s), lambda b, j: (b, j)),
+            # (B, 1, S): a (1, block_s) block over (B, S) is refused by
+            # the TPU lowering
+            pl.BlockSpec((1, 1, block_s), lambda b, j: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, H, r), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, r), q_lat.dtype),
@@ -92,4 +94,4 @@ def mla_decode_ctx(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array,
             pltpu.VMEM((H, r), jnp.float32),
         ],
         interpret=interpret,
-    )(q_lat, q_rope, ckv, k_rope, valid)
+    )(q_lat, q_rope, ckv, k_rope, valid.reshape(B, 1, S))

@@ -2,9 +2,10 @@
 
 The models call these — never the kernels or oracles directly — so the same
 model code runs the Pallas path on real TPU hardware and the numerically
-identical jnp path on CPU (tests, dry-run lowering). Set
-``REPRO_FORCE_PALLAS=interpret`` to exercise the Pallas kernels in interpret
+identical jnp path on CPU (tests, dry-run lowering). On the CPU,
+``REPRO_FORCE_PALLAS=interpret`` exercises the Pallas kernels in interpret
 mode from the model layer (slow; used by a couple of integration tests).
+On a TPU neither the oracle nor interpret mode is ever taken.
 """
 from __future__ import annotations
 
@@ -21,11 +22,10 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _mode() -> str:
-    forced = os.environ.get("REPRO_FORCE_PALLAS", "")
-    if forced == "interpret":
-        return "interpret"
     if jax.default_backend() == "tpu":
         return "tpu"
+    if os.environ.get("REPRO_FORCE_PALLAS", "") == "interpret":
+        return "interpret"
     return "ref"
 
 

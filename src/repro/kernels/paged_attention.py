@@ -18,7 +18,7 @@ Validity is reconstructed in-kernel from the prefetched lengths
 past a sequence's live prefix (including the conventional scratch page)
 are masked to -inf before the softmax, so their garbage contributes an
 exact 0.0. The int8 variant dequantises pages in VMEM via scale pages
-``(P, block_size, Hkv)``, mirroring ``_decode_kernel_int8``.
+``(P, block_size, Hkv)``, with the same kernel body as the fp one.
 """
 from __future__ import annotations
 
@@ -32,10 +32,17 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, block_size: int, scale: float,
-                  softcap: float):
+def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, *rest,
+                  block_size: int, scale: float, softcap: float,
+                  quantized: bool):
+    """One (batch, kv_head, logical-block) step. ``quantized`` adds two
+    scale-page refs after ``v_ref``; they scale the score and probability
+    columns exactly as in ``decode_attention._decode_kernel``."""
     del table_ref  # consumed by the BlockSpec index maps (page lookup)
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -53,7 +60,10 @@ def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     offs = jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
     valid = j * block_size + offs < lengths_ref[b]  # (1, bs)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G, bs)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bs)
+    if quantized:
+        s = s * ks_ref[0, 0, :, :].astype(jnp.float32)
+    s = s * scale
     if softcap and softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
     s = jnp.where(valid, s, NEG_INF)
@@ -63,6 +73,8 @@ def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
     l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+    if quantized:
+        p = p * vs_ref[0, 0, :, :].astype(jnp.float32)
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())))
     m_scr[...] = m_new
@@ -73,58 +85,13 @@ def _paged_kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _paged_kernel_int8(table_ref, lengths_ref, q_ref, k_ref, v_ref,
-                       ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                       block_size: int, scale: float, softcap: float):
-    """int8-page variant: pages are dequantised IN VMEM (per-token,
-    per-head absmax scale pages) — HBM traffic is int8 bytes + scales."""
-    del table_ref
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, 0, :, :].astype(jnp.float32)               # (G, K)
-    ks = ks_ref[0, 0, :].astype(jnp.float32)                # (bs,)
-    vs = vs_ref[0, 0, :].astype(jnp.float32)
-    k = k_ref[0, 0, :, :].astype(jnp.float32) * ks[:, None]
-    v = v_ref[0, 0, :, :].astype(jnp.float32) * vs[:, None]
-    offs = jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
-    valid = j * block_size + offs < lengths_ref[b]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    if softcap and softcap > 0.0:
-        s = jnp.tanh(s / softcap) * softcap
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())))
-    m_scr[...] = m_new
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
-def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
-                           v_pages: jax.Array, table: jax.Array,
-                           lengths: jax.Array, *, softcap: float = 0.0,
-                           interpret: bool = False) -> jax.Array:
-    """q: (B, H, K); k_pages/v_pages: (P, bs, Hkv, K); table: (B, nblk)
-    int32; lengths: (B,) int32 -> (B, H, K)."""
+def _paged_call(q, k_pages, v_pages, scale_pages, table, lengths, *,
+                softcap: float, interpret: bool) -> jax.Array:
+    """Shared pallas_call. Scale pages ride as ``(P, Hkv, 1, bs)`` so
+    their blocks' last two dims are the array's own — a ``(1, bs)`` block
+    over ``(P, Hkv, bs)`` is refused by the TPU lowering."""
     B, H, K = q.shape
-    bs, Hkv = k_pages.shape[1], k_pages.shape[2]
+    P, bs, Hkv = k_pages.shape[:3]
     nblk = table.shape[1]
     G = H // Hkv
     grid = (B, Hkv, nblk)
@@ -132,19 +99,25 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     qg = q.reshape(B, Hkv, G, K)
     kt = jnp.moveaxis(k_pages, 2, 1)               # (P, Hkv, bs, K)
     vt = jnp.moveaxis(v_pages, 2, 1)
+    page = pl.BlockSpec((1, 1, bs, K),
+                        lambda b, h, j, t, ln: (t[b, j], h, 0, 0))
+    in_specs = [
+        pl.BlockSpec((1, 1, G, K), lambda b, h, j, t, ln: (b, h, 0, 0)),
+        page, page,
+    ]
+    args = [qg, kt, vt]
+    for sc in scale_pages:                         # (P, bs, Hkv) each
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 1, bs), lambda b, h, j, t, ln: (t[b, j], h, 0, 0)))
+        args.append(jnp.moveaxis(sc, 2, 1).reshape(P, Hkv, 1, bs))
 
     kernel = functools.partial(_paged_kernel, block_size=bs,
-                               scale=K ** -0.5, softcap=softcap)
+                               scale=K ** -0.5, softcap=softcap,
+                               quantized=bool(scale_pages))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                     # table, lengths
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, G, K), lambda b, h, j, t, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, K),
-                         lambda b, h, j, t, ln: (t[b, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, K),
-                         lambda b, h, j, t, ln: (t[b, j], h, 0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, G, K),
                                lambda b, h, j, t, ln: (b, h, 0, 0)),
         scratch_shapes=[
@@ -158,8 +131,19 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, K), q.dtype),
         interpret=interpret,
-    )(table.astype(jnp.int32), lengths.astype(jnp.int32), qg, kt, vt)
+    )(table.astype(jnp.int32), lengths.astype(jnp.int32), *args)
     return out.reshape(B, H, K)
+
+
+@functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
+def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
+                           v_pages: jax.Array, table: jax.Array,
+                           lengths: jax.Array, *, softcap: float = 0.0,
+                           interpret: bool = False) -> jax.Array:
+    """q: (B, H, K); k_pages/v_pages: (P, bs, Hkv, K); table: (B, nblk)
+    int32; lengths: (B,) int32 -> (B, H, K)."""
+    return _paged_call(q, k_pages, v_pages, (), table, lengths,
+                       softcap=softcap, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
@@ -172,47 +156,5 @@ def paged_decode_attention_int8(q: jax.Array, k_pages: jax.Array,
                                 interpret: bool = False) -> jax.Array:
     """q: (B,H,K) fp; k/v pages: (P, bs, Hkv, K) int8; scale pages:
     (P, bs, Hkv) f32; table: (B, nblk) int32; lengths: (B,) int32."""
-    B, H, K = q.shape
-    bs, Hkv = k_pages.shape[1], k_pages.shape[2]
-    nblk = table.shape[1]
-    G = H // Hkv
-    grid = (B, Hkv, nblk)
-
-    qg = q.reshape(B, Hkv, G, K)
-    kt = jnp.moveaxis(k_pages, 2, 1)               # (P, Hkv, bs, K)
-    vt = jnp.moveaxis(v_pages, 2, 1)
-    kst = jnp.moveaxis(k_scale_pages, 2, 1)        # (P, Hkv, bs)
-    vst = jnp.moveaxis(v_scale_pages, 2, 1)
-
-    kernel = functools.partial(_paged_kernel_int8, block_size=bs,
-                               scale=K ** -0.5, softcap=softcap)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, G, K), lambda b, h, j, t, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, K),
-                         lambda b, h, j, t, ln: (t[b, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, K),
-                         lambda b, h, j, t, ln: (t[b, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, bs),
-                         lambda b, h, j, t, ln: (t[b, j], h, 0)),
-            pl.BlockSpec((1, 1, bs),
-                         lambda b, h, j, t, ln: (t[b, j], h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, K),
-                               lambda b, h, j, t, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, K), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, K), q.dtype),
-        interpret=interpret,
-    )(table.astype(jnp.int32), lengths.astype(jnp.int32),
-      qg, kt, vt, kst, vst)
-    return out.reshape(B, H, K)
+    return _paged_call(q, k_pages, v_pages, (k_scale_pages, v_scale_pages),
+                       table, lengths, softcap=softcap, interpret=interpret)

@@ -1,17 +1,21 @@
 """Pallas TPU kernel for the Mamba2 SSD (state-space duality) chunked scan.
 
-Grid (batch, S/Q): the chunk axis is TPU-sequential, so the inter-chunk
-recurrent state (nh, hd, ds) lives in VMEM scratch and is carried across
-chunk iterations — the HBM→VMEM traffic per chunk is exactly one tile of
-x/dt/B/C and one tile of y, the minimum possible for this op.
+Grid (batch, head, S/Q): the chunk axis is TPU-sequential, so the head's
+inter-chunk recurrent state (hd, ds) lives in VMEM scratch and is carried
+across chunk iterations — the HBM→VMEM traffic per chunk is exactly one
+tile of x/dt/B/C and one tile of y, the minimum possible for this op.
 
-Within a chunk the SSD quadratic form is three MXU matmuls per head
-(G = C·Bᵀ, masked-decay weighting, y = M·(dt·x)) plus the carried-state
-contribution. Heads are vectorised in-kernel (the head axis is folded into
-the matmul batch via dot_general batching dims).
+Each grid step is plain 2-D math on one head: three MXU matmuls (G = C·Bᵀ,
+y = (G∘L)·(dt·x), the carried-state term) plus the state update. The
+wrapper lays every operand out head-major, so each block's last two dims
+are a (Q, feature) tile, and dt·A arrives as a (Q, 1) column. The TPU
+lowering has no cumsum, so the within-chunk cumulative sum is a masked
+reduction over a (Q, Q) tile; a second one, over the diagonal, turns that
+row into the column the decay needs, exactly. The skip term D·x is added
+by the wrapper.
 
-All decay math runs in fp32; the recurrence is numerically identical to the
-oracle in ref.py (same segsum formulation).
+All decay math runs in fp32; the recurrence is the oracle's in ref.py
+(same segsum formulation).
 """
 from __future__ import annotations
 
@@ -23,65 +27,53 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, A_ref, b_ref, c_ref, d_ref, y_ref, st_ref,
-                state_scr, *, chunk: int, nh: int, hd: int, ds: int,
-                ng: int):
-    c_idx = pl.program_id(1)
+def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, y_ref, st_ref,
+                state_scr, *, chunk: int):
+    c_idx = pl.program_id(2)
 
     @pl.when(c_idx == 0)
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    Q = chunk
-    x = x_ref[0].astype(jnp.float32)          # (Q, nh, hd)
-    dt = dt_ref[0].astype(jnp.float32)        # (Q, nh)
-    A = A_ref[...].astype(jnp.float32)        # (nh,)
-    B_ = b_ref[0].astype(jnp.float32)         # (Q, ng, ds)
-    C_ = c_ref[0].astype(jnp.float32)         # (Q, ng, ds)
-    D = d_ref[...].astype(jnp.float32)        # (nh,)
+    f32 = jnp.float32
+    x = x_ref[0, 0].astype(f32)               # (Q, hd)
+    dt = dt_ref[0, 0].astype(f32)             # (Q, 1)
+    da = da_ref[0, 0]                         # (Q, 1) dt·A, f32
+    Bm = b_ref[0, 0].astype(f32)              # (Q, ds)
+    Cm = c_ref[0, 0].astype(f32)              # (Q, ds)
 
-    rep = nh // ng
-    Bh = jnp.repeat(B_, rep, axis=1)          # (Q, nh, ds)
-    Ch = jnp.repeat(C_, rep, axis=1)
-
-    dA = dt * A[None, :]                      # (Q, nh)
-    dA_cum = jnp.cumsum(dA, axis=0)           # inclusive
-    # decay matrix L[h, q, j] = exp(cum[q] - cum[j]) for j <= q
-    diff = dA_cum.T[:, :, None] - dA_cum.T[:, None, :]       # (nh, Q, Q)
-    qi = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    ki = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where((ki <= qi)[None], jnp.exp(diff), 0.0)
+    qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    ki = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = ki <= qi
+    # inclusive within-chunk cumsum of dt·A as a row, then as a column
+    cum_row = jnp.sum(jnp.where(qi <= ki, da, 0.0), axis=0,
+                      keepdims=True)                          # (1, Q)
+    cum_col = jnp.sum(jnp.where(qi == ki, cum_row, 0.0), axis=1,
+                      keepdims=True)                          # (Q, 1)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    total = jnp.sum(jnp.where(last, cum_row, 0.0), axis=1,
+                    keepdims=True)                            # (1, 1)
+    # decay L[q, k] = exp(cum[q] - cum[k]) for k <= q
+    L = jnp.where(causal, jnp.exp(cum_col - cum_row), 0.0)
 
     # intra-chunk quadratic term
-    G = jax.lax.dot_general(
-        jnp.moveaxis(Ch, 1, 0), jnp.moveaxis(Bh, 1, 0),
-        (((2,), (2,)), ((0,), (0,))))                         # (nh, Q, Q)
-    M = G * L                                                 # (nh, Q, Q)
-    dtx = x * dt[:, :, None]                                  # (Q, nh, hd)
-    y_diag = jax.lax.dot_general(
-        M, jnp.moveaxis(dtx, 1, 0), (((2,), (1,)), ((0,), (0,))))  # (nh, Q, hd)
+    G = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))  # (Q, Q)
+    dtx = x * dt                                              # (Q, hd)
+    y_diag = jax.lax.dot_general(G * L, dtx, (((1,), (0,)), ((), ())))
 
     # carried-in state contribution: y_off[q] = exp(cum[q]) * C_q · state
-    state = state_scr[...]                                    # (nh, hd, ds)
-    y_off = jax.lax.dot_general(
-        jnp.moveaxis(Ch, 1, 0), state, (((2,), (2,)), ((0,), (0,))))  # (nh, Q, hd)
-    y_off = y_off * jnp.exp(dA_cum).T[:, :, None]
+    state = state_scr[...]                                    # (hd, ds)
+    y_off = jax.lax.dot_general(Cm, state, (((1,), (1,)), ((), ())))
+    y_ref[0, 0] = (y_diag + y_off * jnp.exp(cum_col)).astype(y_ref.dtype)
 
-    y = y_diag + y_off + jnp.moveaxis(x, 1, 0) * D[:, None, None]
-    y_ref[0] = jnp.moveaxis(y, 0, 1).astype(y_ref.dtype)      # (Q, nh, hd)
+    # state update: decay the whole chunk + within-chunk contributions
+    wx = dtx * jnp.exp(total - cum_col)                       # (Q, hd)
+    new_contrib = jax.lax.dot_general(wx, Bm, (((0,), (0,)), ((), ())))
+    state_scr[...] = state * jnp.exp(total) + new_contrib
 
-    # state update: decay full chunk + within-chunk contributions
-    decay_to_end = jnp.exp(dA_cum[-1, :][None, :] - dA_cum)   # (Q, nh)
-    wx = dtx * decay_to_end[:, :, None]                       # (Q, nh, hd)
-    new_contrib = jax.lax.dot_general(
-        jnp.moveaxis(wx, 1, 0), jnp.moveaxis(Bh, 1, 0),
-        (((1,), (1,)), ((0,), (0,))))                         # (nh, hd, ds)
-    chunk_decay = jnp.exp(dA_cum[-1, :])                      # (nh,)
-    state_scr[...] = state * chunk_decay[:, None, None] + new_contrib
-
-    @pl.when(c_idx == pl.num_programs(1) - 1)
+    @pl.when(c_idx == pl.num_programs(2) - 1)
     def _emit_state():
-        st_ref[0] = state_scr[...].astype(st_ref.dtype)
+        st_ref[0, 0] = state_scr[...].astype(st_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -91,32 +83,38 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B_: jax.Array,
     """Shapes as in ref.ssd_scan. Returns (y, final_state)."""
     Bb, S, nh, hd = x.shape
     ng, ds = B_.shape[2], B_.shape[3]
+    rep = nh // ng
     chunk = min(chunk, S)
     assert S % chunk == 0, (S, chunk)
-    grid = (Bb, S // chunk)
+    grid = (Bb, nh, S // chunk)
+    f32 = jnp.float32
 
-    kernel = functools.partial(_ssd_kernel, chunk=chunk, nh=nh, hd=hd,
-                               ds=ds, ng=ng)
+    head_major = functools.partial(jnp.moveaxis, source=2, destination=1)
+    dA = head_major(dt.astype(f32) * A.astype(f32))          # (Bb, nh, S)
+    kernel = functools.partial(_ssd_kernel, chunk=chunk)
     y, st = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, nh, hd), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, chunk, nh), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((nh,), lambda b, c: (0,)),
-            pl.BlockSpec((1, chunk, ng, ds), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, chunk, ng, ds), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((nh,), lambda b, c: (0,)),
+            pl.BlockSpec((1, 1, chunk, hd), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, chunk, ds),
+                         lambda b, h, c: (b, h // rep, c, 0)),
+            pl.BlockSpec((1, 1, chunk, ds),
+                         lambda b, h, c: (b, h // rep, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, nh, hd), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, nh, hd, ds), lambda b, c: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, chunk, hd), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, hd, ds), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bb, S, nh, hd), x.dtype),
+            jax.ShapeDtypeStruct((Bb, nh, S, hd), f32),
             jax.ShapeDtypeStruct((Bb, nh, hd, ds), x.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((nh, hd, ds), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hd, ds), f32)],
         interpret=interpret,
-    )(x, dt, A, B_, C_, D)
-    return y, st
+    )(head_major(x), head_major(dt)[..., None], dA[..., None],
+      head_major(B_), head_major(C_))
+    y = jnp.moveaxis(y, 1, 2) + x.astype(f32) * D.astype(f32)[:, None]
+    return y.astype(x.dtype), st

@@ -14,7 +14,6 @@ import argparse
 
 import jax
 
-from repro.compat import set_mesh
 from repro.configs.registry import get_config, get_shape
 from repro.core.hlo_analysis import (_parse_computation, _split_computations,
                                      analyze_hlo)
@@ -47,7 +46,7 @@ def lower_text(arch, shape_name, multi_pod=False, microbatches=1,
     rules = ShardingRules(mesh, train=(shape.kind == "train"), fsdp=fsdp,
                           decode=(shape.kind == "decode"))
     in_sh = _shardings_for(rules, shape.kind, args)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step, in_shardings=in_sh).lower(*args).compile()
         mem = compiled.memory_analysis()
         txt = compiled.as_text()

@@ -11,7 +11,6 @@ import traceback
 
 import jax
 
-from repro.compat import set_mesh
 from repro.configs.base import INPUT_SHAPES
 from repro.configs.registry import assigned_pairs, get_config, get_shape
 from repro.core.hlo_analysis import analyze_hlo
@@ -80,7 +79,7 @@ def run_one(arch: str, shape_name: str, mesh_name: str,
         out_shardings = (None, in_shardings[1])
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = (jax.jit(step, in_shardings=in_shardings,
                           out_shardings=out_shardings)
                   if out_shardings is not None

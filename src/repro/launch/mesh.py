@@ -2,7 +2,15 @@
 jax device state (the dry-run sets XLA_FLAGS before any jax import)."""
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+
+
+def make_mesh(axis_shapes, axis_names, **kwargs):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules
+    place arrays with ``NamedSharding`` and leave propagation to GSPMD."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -17,15 +17,25 @@ places each container on a disjoint slice of the host's jax devices
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``);
 ``--isolation process`` runs one OS process per container pinned to a
 disjoint core set before jax initialises (the paper's
-``docker run --cpus=C/n``).
+``docker run --cpus=C/n``). Process isolation is CPU-only: a TPU
+belongs to one process, so on a TPU host it is refused and
+``--submesh`` is the container.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b \
+``--arch`` names the config exactly as the registry does: ``qwen3-0.6b``
+is the published widths, ``qwen3-0.6b-reduced`` the CPU-sized toy the
+examples below use. A container failure (an engine step that raised)
+ends the run with a non-zero exit code, even when its requests were
+retried to completion.
+
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b-reduced \
         --containers 4 --requests 16 --stream
-    PYTHONPATH=src python -m repro.launch.serve --waves 8 --objective time
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b-reduced \
+        --waves 8 --objective time
     XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
-        python -m repro.launch.serve --containers 2 --submesh
-    PYTHONPATH=src python -m repro.launch.serve --containers 2 \
-        --isolation process --total-cores 2 --stream
+        python -m repro.launch.serve --arch qwen3-0.6b-reduced \
+        --containers 2 --submesh
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b-reduced \
+        --containers 2 --isolation process --total-cores 2 --stream
 """
 from __future__ import annotations
 
@@ -34,6 +44,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.registry import ARCH_NAMES, get_config
 from repro.core.containers import feasible_counts
 from repro.core.testbed import available_cores
@@ -42,7 +53,7 @@ from repro.models.model import Model
 from repro.serving import ChunkEvent, EngineConfig, Request, Router
 from repro.serving.adaptive import AdaptiveServingPool
 from repro.serving.backend import (ProcessBackend, SubmeshBackend,
-                                   ThreadBackend)
+                                   ThreadBackend, process_isolation_refusal)
 from repro.serving.pool import ContainerServingPool
 from repro.serving.process_pool import ProcessContainerPool
 from repro.workload.replay import replay
@@ -105,9 +116,22 @@ def _stream_requests(router: Router, requests, verbose_chunks: bool):
     return handles
 
 
+def _exit_on_container_failures(router: Router) -> None:
+    """A container whose engine step raised fails the run, even when the
+    Router retried its requests to completion elsewhere."""
+    fails = router.container_failures
+    if fails:
+        raise SystemExit(f"{len(fails)} container failure(s); the first:\n"
+                         f"{fails[0].message}")
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen3-0.6b", choices=ARCH_NAMES)
+    ap.add_argument("--arch", default="qwen3-0.6b",
+                    choices=ARCH_NAMES + tuple(f"{a}-reduced"
+                                               for a in ARCH_NAMES),
+                    help="registry name; append -reduced for the "
+                         "CPU-sized variant")
     ap.add_argument("--containers", type=int, default=0,
                     help="0 = let the scheduler choose online")
     ap.add_argument("--requests", type=int, default=16)
@@ -200,8 +224,13 @@ def main() -> None:
     if args.isolation == "process" and args.submesh:
         ap.error("--submesh needs one process owning all devices; pick "
                  "either --submesh or --isolation process")
+    use_compile_cache()
+    if args.isolation == "process":
+        refusal = process_isolation_refusal()
+        if refusal:
+            ap.error(refusal)
 
-    cfg = get_config(args.arch + "-reduced")
+    cfg = get_config(args.arch)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
@@ -263,6 +292,7 @@ def main() -> None:
                           f"{ttfc[-1] * 1e3:.1f}ms")
                 _print_wave(args, n, done, per, wall, energy, meshes,
                             router.backend)
+            _exit_on_container_failures(router)
             return
         backend = _make_backend(args, cfg, model, params, n, units)
         meshes = getattr(backend, "meshes", None)
@@ -303,6 +333,7 @@ def main() -> None:
         print(f"converged choice: n={router.choice}")
         print("scheduler summary:", router.scheduler.summary())
         router.close()
+        _exit_on_container_failures(router)
         return
     apool = AdaptiveServingPool(model, params, feasible,
                                 objective=args.objective, epsilon=0.2,
@@ -388,6 +419,7 @@ def _serve_trace(args, cfg, model, params, units, slo) -> None:
                if cw.attained is not None else "")
         print(f"  [{name}] done {cw.n_done} shed {cw.n_shed} "
               f"failed {cw.n_failed} ttfc p95 {cw.ttfc_p95_s:.3f}s{tgt}")
+    _exit_on_container_failures(router)
 
 
 def _print_wave(args, n, done, per, wall, energy, meshes, backend) -> None:

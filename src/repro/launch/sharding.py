@@ -81,8 +81,7 @@ class ShardingRules:
         meshes) — a container pool checks these are pairwise disjoint."""
         try:
             devs = self.mesh.devices
-        except (AttributeError, ValueError):
-            # AbstractMesh has no devices (0.4.x raises ValueError)
+        except ValueError:              # an AbstractMesh has no devices
             return frozenset()
         return frozenset(devs.flat)
 

@@ -16,9 +16,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import make_mesh, set_mesh
+from repro.compile_cache import use_compile_cache
 from repro.configs.registry import ARCH_NAMES, get_config
 from repro.data.pipeline import LmTokenStream
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import ShardingRules
 from repro.models.model import Model
 from repro.train import checkpoint
@@ -47,6 +48,7 @@ def main() -> None:
     ap.add_argument("--save", default=None)
     args = ap.parse_args()
 
+    use_compile_cache()
     name = args.arch + ("-reduced" if args.reduced else "")
     cfg = get_config(name)
     model = Model(cfg)
@@ -62,7 +64,7 @@ def main() -> None:
     stream = LmTokenStream(cfg.vocab_size, seq_len=args.seq,
                            batch_size=args.batch)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(
             lambda k: model.init(k),
             out_shardings=rules.params(jax.eval_shape(

@@ -18,11 +18,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import get_abstract_mesh, shard_map
 from repro.configs.base import ArchConfig
 from repro.kernels import ops as kops
-from repro.models.layers import (apply_rope, init_rmsnorm, rmsnorm_fwd,
-                                 truncated_normal)
+from repro.models.layers import (apply_rope, init_rmsnorm, mesh_axis_sizes,
+                                 rmsnorm_fwd, truncated_normal)
 
 NEG_INF = -1e30
 
@@ -163,9 +162,7 @@ def _seq_parallel_decode(cfg: ArchConfig, q, k, v, valid,
 
     from repro.kernels import ref as kref
 
-    mesh = get_abstract_mesh()
-    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes)) if mesh.axis_names \
-        else {}
+    sizes = mesh_axis_sizes()
     msize = sizes.get("model", 1)
     dax = tuple(a for a in ("pod", "data") if sizes.get(a, 1) > 1)
     dsize = 1
@@ -211,7 +208,7 @@ def _seq_parallel_decode(cfg: ArchConfig, q, k, v, valid,
     sspec = P(bentry, seq_axes, hentry)            # (B, W, kv)
     scale_args = ((k_scale, v_scale) if use_scales
                   else (jnp.zeros((B, W, kv), jnp.float32),) * 2)
-    return shard_map(
+    return jax.shard_map(
         kernel,
         in_specs=(qspec, cspec, cspec, vspec, sspec, sspec),
         out_specs=qspec)(q, k, v, valid, *scale_args)

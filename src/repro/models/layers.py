@@ -8,7 +8,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import get_abstract_mesh
 from repro.configs.base import ArchConfig
 
 
@@ -19,16 +18,25 @@ def truncated_normal(key, shape, dtype, scale):
 # ---------------------------------------------------------------------------
 # activation sharding constraints (no-ops without a mesh context)
 # ---------------------------------------------------------------------------
+def mesh_axis_sizes() -> dict[str, int]:
+    """Sizes of the ambient mesh's axes that sharding may still use: none
+    without a mesh, and none that ``shard_map`` made manual (inside it
+    each device already holds its own block)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return {n: s for n, s, t in zip(mesh.axis_names, mesh.axis_sizes,
+                                    mesh.axis_types)
+            if t != jax.sharding.AxisType.Manual}
+
+
 def constrain(x: jax.Array, *spec) -> jax.Array:
     """``with_sharding_constraint`` that degrades to identity when no mesh
     is set (CPU tests) and silently drops axes that are absent from the
     ambient mesh or don't divide the corresponding dim. ``spec`` entries are
     axis names, tuples of names, or None — one per array dim (trailing dims
     may be omitted)."""
-    mesh = get_abstract_mesh()
-    if not mesh.axis_names:
+    sizes = mesh_axis_sizes()
+    if not sizes:
         return x
-    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     parts = []
     for i, s in enumerate(spec):
         names = s if isinstance(s, tuple) else ((s,) if s else ())

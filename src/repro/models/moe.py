@@ -18,17 +18,16 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh, shard_map
 from repro.configs.base import ArchConfig
-from repro.models.layers import constrain, init_mlp, mlp_fwd, truncated_normal
+from repro.models.layers import (constrain, init_mlp, mesh_axis_sizes,
+                                 mlp_fwd, truncated_normal)
 
 
 def _mesh_info():
     """(data_axes, data_size, model_size) of the ambient mesh (if any)."""
-    mesh = get_abstract_mesh()
-    if not mesh.axis_names:
+    sizes = mesh_axis_sizes()
+    if not sizes:
         return (), 1, 1
-    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     dax = tuple(a for a in ("pod", "data") if sizes.get(a, 1) > 1)
     dsize = 1
     for a in dax:
@@ -134,7 +133,7 @@ def _dispatch_shard_map(experts: dict, cfg: ArchConfig, xt: jax.Array,
     else:  # ff dim sharded: (E, d, ff) for up/gate, (E, ff, d) for down
         wspec = {k: (P(None, "model") if k == "w_down"
                      else P(None, None, "model")) for k in experts}
-    out = shard_map(
+    out = jax.shard_map(
         region,
         in_specs=(wspec, dspec, dspec, dspec, dspec, dspec),
         out_specs=dspec)(experts, safe_e, safe_pos, keep, gates, tok_rep)

@@ -197,8 +197,9 @@ class ThreadBackend:
         """Convert an engine-step exception into a ContainerFailure event
         and either rebuild the engine (bounded) or trip the breaker."""
         eng = self.engines[cid]
-        lost = tuple(r.rid for r in eng.queue) + tuple(
-            s.rid for s in eng.slots if s.active)
+        lost = tuple(r.rid for r in eng.queue) + eng.admitting + tuple(
+            s.rid for s in eng.slots if s.active and s.rid not in
+            eng.admitting)
         tb = "".join(traceback.format_exception(
             type(exc), exc, exc.__traceback__))
         fail = ContainerFailure(
@@ -450,6 +451,22 @@ def share_params(params: Any) -> ParamsShare:
 # ---------------------------------------------------------------------------
 # process backend
 # ---------------------------------------------------------------------------
+def process_isolation_refusal() -> str | None:
+    """Why process containers cannot start here, or None when they can.
+    Each child imports jax and needs the device; an accelerator belongs
+    to one process, and this parent already holds it (a TPU child would
+    fail or hang). Process containers are the CPU-core mechanism; on an
+    accelerator host ``SubmeshBackend`` is the container."""
+    import jax
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return ("process isolation needs a CPU parent: this process holds "
+                f"the {platform} device, which one process owns at a time; "
+                "use submesh placement (--submesh / SubmeshBackend) for "
+                "containers on an accelerator")
+    return None
+
+
 def _engine_config_wire(config: EngineConfig) -> dict:
     """EngineConfig as a dict of picklable primitives. Pickling the
     dataclass itself would make the child unpickle (hence import
@@ -501,6 +518,9 @@ class ProcessBackend:
                  respawn_backoff_s: float = 0.25,
                  heartbeat_s: float = 0.5,
                  heartbeat_timeout_s: float | None = 60.0):
+        refusal = process_isolation_refusal()
+        if refusal:
+            raise RuntimeError(refusal)
         self.cfg = cfg
         self.capacity = n_containers
         self.config = config or EngineConfig(

@@ -60,7 +60,10 @@ at construction — reused across every wave the pool serves. All jitted
 calls then execute on the sub-mesh (committed inputs pin the computation),
 cache donation included, and outputs never leave the slice; replicated
 placement keeps the container bit-identical to the single-device baseline
-(see launch/sharding.ShardingRules.container_placement).
+(see launch/sharding.ShardingRules.container_placement). On a sub-mesh of
+several devices the model programs run once per device under
+``shard_map`` (each device holds a whole replica): the TPU compiler does
+not partition a Pallas kernel by itself.
 
 This is the per-container serving loop; core/splitter.py +
 serving/pool.py run n of these over disjoint resource shares — the paper's
@@ -79,6 +82,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from repro.core.roofline import decode_chunk_tokens
 from repro.models.cache import PagedLayout
@@ -240,6 +244,10 @@ class ServingEngine:
     on_event: Callable[[Any], None] | None = None
     container_id: int = 0
     fault: Any = None
+    # rids popped off the queue by the admission round in progress and
+    # not yet in a slot: a step that raises there (a prefill that fails
+    # to compile) must still report them lost
+    admitting: tuple = ()
 
     def __init__(self, model: Model, params: Any,
                  config: EngineConfig | None = None, *,
@@ -293,6 +301,10 @@ class ServingEngine:
                                     layout=layout)
         self.device_set = (self.rules.device_set if self.rules is not None
                            else frozenset())
+        # a sub-mesh of several devices holds one replica per device; its
+        # programs are per-device (see _jit) and keyed by the mesh
+        self._replicas = (mesh if mesh is not None and mesh.devices.size > 1
+                          else None)
         self.slots = [_Slot() for _ in range(n_rows)]
         self.queue: deque[Request] = deque()
         self.done: list[Completion] = []
@@ -306,9 +318,10 @@ class ServingEngine:
                 context_tokens=config.max_len if self.paged else 0))
         self._key = jax.random.PRNGKey(config.seed)
         self._jits = _shared_jits(model)
-        if "decode" not in self._jits:
-            self._jits["decode"] = jax.jit(model.decode_step)
-        self._decode = self._jits["decode"]
+        key = ("decode", self._replicas)
+        if key not in self._jits:
+            self._jits[key] = self._jit(model.decode_step)
+        self._decode = self._jits[key]
         # which axis of each cache leaf is the batch/slot axis (None for
         # scalar or batch-free leaves) — inferred once from shape structs so
         # row insertion never has to guess from runtime shapes (which is
@@ -407,15 +420,27 @@ class ServingEngine:
         cfg = self.model.cfg
         return not (cfg.is_ssm or cfg.sliding_window > 0)
 
+    def _jit(self, fn, **jit_kw):
+        """``jax.jit`` for this engine's placement: on a multi-device
+        sub-mesh every device runs ``fn`` whole on its own replica
+        (``shard_map`` with everything replicated). The body has no
+        collectives, and a Pallas call's output carries no varying-axes
+        annotation, so the varying-axes check is off."""
+        if self._replicas is not None:
+            rep = PartitionSpec()
+            fn = jax.shard_map(fn, mesh=self._replicas, in_specs=rep,
+                               out_specs=rep, check_vma=False)
+        return jax.jit(fn, **jit_kw)
+
     def _prefill_fn(self, n_seqs: int, bl: int):
-        key = ("prefill", n_seqs, bl, self.max_len)
+        key = ("prefill", n_seqs, bl, self.max_len, self._replicas)
         if key not in self._jits:
             m, ml = self.model, self.max_len
 
             def fn(params, batch, logits_idx):
                 cache = m.init_cache(n_seqs, ml)
                 return m.prefill(params, batch, cache, logits_at=logits_idx)
-            self._jits[key] = jax.jit(fn)
+            self._jits[key] = self._jit(fn)
         return self._jits[key]
 
     def _suffix_prefill_fn(self, n_seqs: int, bl: int, offset: int):
@@ -424,7 +449,8 @@ class ServingEngine:
         PROMPT_BUCKETS-padded suffix width — suffix shapes reuse the same
         bucket table as full prefill, so compiled-shape count stays
         bounded."""
-        key = ("prefill_sfx", n_seqs, bl, offset, self.max_len)
+        key = ("prefill_sfx", n_seqs, bl, offset, self.max_len,
+               self._replicas)
         if key not in self._jits:
             m = self.model
 
@@ -432,21 +458,21 @@ class ServingEngine:
                 cache = m.init_cache(n_seqs, bl)
                 return m.prefill_suffix(params, batch, cache, ctx, offset,
                                         logits_at=logits_idx)
-            self._jits[key] = jax.jit(fn)
+            self._jits[key] = self._jit(fn)
         return self._jits[key]
 
     def _chunk_fn(self, n_tokens: int):
         """Fused decode executable for a chunk of ``n_tokens`` steps; the
         engine cache is donated (arg 1), so the KV rings update in place."""
         key = ("chunk", n_tokens, self.max_len, self.greedy,
-               "paged" if self.paged else "dense")
+               "paged" if self.paged else "dense", self._replicas)
         if key not in self._jits:
             m, ml, greedy = self.model, self.max_len, self.greedy
 
             def fn(params, cache, state):
                 return m.decode_chunk(params, cache, state, n_tokens,
                                       max_len=ml, greedy=greedy)
-            self._jits[key] = jax.jit(fn, donate_argnums=(1,))
+            self._jits[key] = self._jit(fn, donate_argnums=(1,))
         return self._jits[key]
 
     def _insert_rows(self, src_cache: Any, slot_ids: list[int]) -> None:
@@ -612,6 +638,7 @@ class ServingEngine:
 
     def _admit_batch(self, slot_ids: list[int], reqs: list[Request],
                      plans: list | None = None) -> None:
+        self.admitting = tuple(r.rid for r in reqs)
         n = len(reqs)
         nv = self.model.cfg.n_vision_tokens or 0
         H = plans[0][0] if plans and plans[0] is not None else 0
@@ -674,6 +701,7 @@ class ServingEngine:
             # the prefill sample is the request's first streamed chunk —
             # its arrival is the time-to-first-chunk the Router windows
             self._emit_chunk(r.rid, (int(first[j]),), now)
+        self.admitting = ()
         self.peak_active = max(self.peak_active,
                                sum(1 for s in self.slots if s.active))
         for i in slot_ids:

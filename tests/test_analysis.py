@@ -237,9 +237,17 @@ def test_kernel_check_catches_non_dividing_block():
     assert "KRN002" in [f.code for f in check_spec(spec)]
 
 
+def test_kernel_check_catches_untiled_last_two_dims():
+    """A (1, block_k) mask block over a (B, W) mask: tiles exactly, but
+    the TPU lowering refuses it (1 is neither a multiple of 8 nor B)."""
+    from repro.analysis.kernels import check_spec
+    spec = _toy_spec((1, 256), lambda i: (i, 0), shape=(2, 512), grid=(2,))
+    assert [f.code for f in check_spec(spec)] == ["KRN011", "KRN011"]
+
+
 def test_kernel_check_passes_valid_spec():
     from repro.analysis.kernels import check_spec
-    spec = _toy_spec((4, 128), lambda i: (i, 0))
+    spec = _toy_spec((8, 128), lambda i: (i, 0), shape=(16, 128))
     assert check_spec(spec) == []
 
 

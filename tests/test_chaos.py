@@ -558,3 +558,28 @@ def test_process_step_error_reports_classified_exit(reduced_models):
     for p in mp.active_children():
         p.join(timeout=10)
     assert mp.active_children() == []
+
+
+def test_prefill_error_fails_request_instead_of_hanging(reduced_models,
+                                                        monkeypatch):
+    """A step that raises inside admission (a prefill that fails to
+    compile) has already popped its requests off the queue: they must
+    still be reported lost and end typed, not wait forever."""
+    model, params = reduced_models["qwen3-0.6b"]
+
+    def broken_prefill(self, n_seqs, bl):
+        def fn(*args):
+            raise RuntimeError("prefill failed to compile")
+        return fn
+    monkeypatch.setattr(ServingEngine, "_prefill_fn", broken_prefill)
+    backend = ThreadBackend(model, params, 1, n_slots_per_container=2,
+                            max_len=64, max_respawns=0)
+    with Router(backend, max_retries=1) as router:
+        h = router.submit(_requests(model.cfg, [(6, 4)], seed=41)[0])
+        for _ in range(20):
+            router.poll()
+            if h.done:
+                break
+        assert h.done and h.failure is not None
+        assert "prefill failed to compile" in backend.failures[0].message
+        assert backend.failures[0].lost_rids == (h.rid,)

@@ -24,7 +24,7 @@ SCRIPT = textwrap.dedent("""
     from repro.models.model import Model
     from repro.train.loop import TrainConfig
 
-    from repro.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_config("qwen3-0.6b-reduced")
     model = Model(cfg)
@@ -43,7 +43,7 @@ SCRIPT = textwrap.dedent("""
         else:
             insh = (rules.params(args[0]), rules.cache(args[1], 8),
                     rules.batch(args[2]))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(step, in_shardings=insh).lower(*args).compile()
             txt = compiled.as_text()
         cost = analyze_hlo(txt)

@@ -20,6 +20,7 @@ from repro.configs.registry import get_config
 from repro.models.model import Model
 from repro.serving import (AdaptiveServingPool, ProcessContainerPool,
                            Request, ServingEngine, share_params)
+from repro.serving.backend import ProcessBackend
 from repro.serving.process_pool import save_params
 
 HOST_CORES = len(os.sched_getaffinity(0))
@@ -215,3 +216,12 @@ def test_process_isolation_incompatible_with_submesh():
                             pool_factory=synthetic_pool_factory(
                                 lambda n: 1.0 / n),
                             isolation="process", submesh_devices=8)
+
+
+def test_process_backend_refuses_a_tpu_parent(small_lm, monkeypatch):
+    """A TPU belongs to one process: a parent that holds it cannot spawn
+    children that need it, so the backend refuses before any spawn."""
+    model, _ = small_lm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="needs a CPU parent"):
+        ProcessBackend(model.cfg, 1)

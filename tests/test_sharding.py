@@ -11,13 +11,8 @@ from repro.configs.registry import get_config
 from repro.launch.sharding import ShardingRules
 from repro.models.model import Model
 
-# jax >= 0.4.36 takes ((name, size), ...); older versions took (shape, names)
-try:
-    MESH = AbstractMesh((("data", 16), ("model", 16)))
-    POD_MESH = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-except TypeError:
-    MESH = AbstractMesh((16, 16), ("data", "model"))
-    POD_MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+POD_MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _specs(tree):
