@@ -54,6 +54,7 @@ from typing import Any, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.cache import PagedLayout, is_paged_group
 
@@ -203,7 +204,7 @@ class DenseCache:
         if key not in self._jits:
             axes = self._axes
 
-            def ins_fn(cache, src, idx):
+            def insert_rows(cache, src, idx):
                 def ins(e, s, ax):
                     if ax is None:
                         return e
@@ -211,7 +212,7 @@ class DenseCache:
                     sm = jnp.moveaxis(s.astype(e.dtype), ax, 0)
                     return jnp.moveaxis(em.at[idx].set(sm), 0, ax)
                 return jax.tree.map(ins, cache, src, axes)
-            self._jits[key] = jax.jit(ins_fn, donate_argnums=(0,))
+            self._jits[key] = jax.jit(insert_rows, donate_argnums=(0,))
         return self._jits[key]
 
     def insert(self, src_cache: Any, rows: list[int],
@@ -448,21 +449,22 @@ class PagedCache:
     def flush(self) -> None:
         if not self._pending:
             return
-        rows, self._pending = self._pending, []
-        if self._has_paged:
-            self.tree = self._clear_fn()(self.tree,
-                                         jnp.asarray(rows, jnp.int32))
-        for row in rows:
-            self.allocator.release(self._blocks[row])
-            # indexed blocks survive on the cache's own reference; once
-            # that is the LAST one they become LRU-evictable
-            for b in self._blocks[row]:
-                if b in self._block_hash and self.allocator.ref(b) == 1:
-                    self._lru[b] = None
-                    self._lru.move_to_end(b)
-            self._blocks[row] = []
-            self._tokens[row] = 0
-            self._hits[row] = 0
+        with TraceAnnotation("cache.flush"):
+            rows, self._pending = self._pending, []
+            if self._has_paged:
+                self.tree = self._clear_fn()(self.tree,
+                                             jnp.asarray(rows, jnp.int32))
+            for row in rows:
+                self.allocator.release(self._blocks[row])
+                # indexed blocks survive on the cache's own reference;
+                # once that is the LAST one they become LRU-evictable
+                for b in self._blocks[row]:
+                    if b in self._block_hash and self.allocator.ref(b) == 1:
+                        self._lru[b] = None
+                        self._lru.move_to_end(b)
+                self._blocks[row] = []
+                self._tokens[row] = 0
+                self._hits[row] = 0
 
     # -- device-tree transforms ----------------------------------------
     def _table_rows(self, rows: list[int]) -> np.ndarray:
@@ -478,7 +480,7 @@ class PagedCache:
         if key not in self._jits:
             scratch = self.layout.scratch_page
 
-            def walk(t, rows):
+            def scrub_rows(t, rows):
                 if isinstance(t, dict) and is_paged_group(t):
                     table = t["table"]
                     sdims = table.ndim - 2
@@ -486,11 +488,10 @@ class PagedCache:
                     tf = tf.at[:, rows, :].set(scratch)
                     return {**t, "table": tf.reshape(table.shape)}
                 if isinstance(t, dict):
-                    return {k: walk(v, rows) for k, v in t.items()}
+                    return {k: scrub_rows(v, rows) for k, v in t.items()}
                 return t
 
-            self._jits[key] = jax.jit(lambda tree, rows: walk(tree, rows),
-                                      donate_argnums=(0,))
+            self._jits[key] = jax.jit(scrub_rows, donate_argnums=(0,))
         return self._jits[key]
 
     def _copy_fn(self):
@@ -498,7 +499,7 @@ class PagedCache:
         (the device half of a copy-on-write fork)."""
         key = ("paged_copy",)
         if key not in self._jits:
-            def walk(t, src, dst):
+            def copy_page(t, src, dst):
                 if isinstance(t, dict) and is_paged_group(t):
                     out = dict(t)
                     # stack depth from the TABLE (always 2 trailing dims):
@@ -516,12 +517,10 @@ class PagedCache:
                         out[dk] = pf.reshape(pages.shape)
                     return out
                 if isinstance(t, dict):
-                    return {k: walk(v, src, dst) for k, v in t.items()}
+                    return {k: copy_page(v, src, dst) for k, v in t.items()}
                 return t
 
-            self._jits[key] = jax.jit(
-                lambda tree, src, dst: walk(tree, src, dst),
-                donate_argnums=(0,))
+            self._jits[key] = jax.jit(copy_page, donate_argnums=(0,))
         return self._jits[key]
 
     def _append_fn(self):
@@ -529,7 +528,7 @@ class PagedCache:
         row's logical block indices at physical pages on device."""
         key = ("paged_append",)
         if key not in self._jits:
-            def walk(t, row_, idxs, pages):
+            def write_table(t, row_, idxs, pages):
                 if isinstance(t, dict) and is_paged_group(t):
                     table = t["table"]
                     sdims = table.ndim - 2
@@ -537,13 +536,11 @@ class PagedCache:
                     tf = tf.at[:, row_, idxs].set(pages)
                     return {**t, "table": tf.reshape(table.shape)}
                 if isinstance(t, dict):
-                    return {k: walk(v, row_, idxs, pages)
+                    return {k: write_table(v, row_, idxs, pages)
                             for k, v in t.items()}
                 return t
 
-            self._jits[key] = jax.jit(
-                lambda tree, row_, idxs, pages:
-                    walk(tree, row_, idxs, pages), donate_argnums=(0,))
+            self._jits[key] = jax.jit(write_table, donate_argnums=(0,))
         return self._jits[key]
 
     def _write_table(self, row: int, start: int, blocks: list[int]) -> None:
@@ -560,7 +557,7 @@ class PagedCache:
         if key not in self._jits:
             bs = self.layout.block_size
 
-            def walk(t, table_rows, pos):
+            def gather_prefix(t, table_rows, pos):
                 if isinstance(t, dict) and is_paged_group(t):
                     out = {}
                     # table-derived stack depth, as in _copy_fn: page
@@ -577,12 +574,11 @@ class PagedCache:
                                             + g.shape[1:])
                     return out
                 if isinstance(t, dict):
-                    return {k: walk(v, table_rows, pos)
+                    return {k: gather_prefix(v, table_rows, pos)
                             for k, v in t.items()}
                 return None
 
-            self._jits[key] = jax.jit(
-                lambda tree, table_rows, pos: walk(tree, table_rows, pos))
+            self._jits[key] = jax.jit(gather_prefix)
         return self._jits[key]
 
     def gather_prefix(self, rows: list[int], n_tokens: int) -> Any:
@@ -646,10 +642,9 @@ class PagedCache:
                 sm = jnp.moveaxis(src.astype(dst.dtype), ax, 0)
                 return jnp.moveaxis(em.at[rows_].set(sm), 0, ax)
 
-            self._jits[key] = jax.jit(
-                lambda tree, src, rows_, table_rows, offset_:
-                    walk(tree, src, axes, rows_, table_rows, offset_),
-                donate_argnums=(0,))
+            def insert_rows(tree, src, rows_, table_rows, offset_):
+                return walk(tree, src, axes, rows_, table_rows, offset_)
+            self._jits[key] = jax.jit(insert_rows, donate_argnums=(0,))
         return self._jits[key]
 
     def insert(self, src_cache: Any, rows: list[int],

@@ -82,6 +82,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec
 
 from repro.core.roofline import decode_chunk_tokens
@@ -289,13 +290,12 @@ class ServingEngine:
             # pool construction must not route n caches through device 0
             self.params = jax.device_put(
                 params, self.rules.container_placement(params))
-            cache_struct = jax.eval_shape(
-                lambda: model.init_cache(n_rows, config.max_len, dtype,
-                                         layout=layout))
-            tree = jax.jit(
-                lambda: model.init_cache(n_rows, config.max_len, dtype,
-                                         layout=layout),
-                out_shardings=self.rules.container_placement(cache_struct))()
+            def init_cache():
+                return model.init_cache(n_rows, config.max_len, dtype,
+                                        layout=layout)
+            placement = self.rules.container_placement(
+                jax.eval_shape(init_cache))
+            tree = jax.jit(init_cache, out_shardings=placement)()
         else:
             tree = model.init_cache(n_rows, config.max_len, dtype,
                                     layout=layout)
@@ -437,10 +437,10 @@ class ServingEngine:
         if key not in self._jits:
             m, ml = self.model, self.max_len
 
-            def fn(params, batch, logits_idx):
+            def prefill(params, batch, logits_idx):
                 cache = m.init_cache(n_seqs, ml)
                 return m.prefill(params, batch, cache, logits_at=logits_idx)
-            self._jits[key] = self._jit(fn)
+            self._jits[key] = self._jit(prefill)
         return self._jits[key]
 
     def _suffix_prefill_fn(self, n_seqs: int, bl: int, offset: int):
@@ -454,11 +454,11 @@ class ServingEngine:
         if key not in self._jits:
             m = self.model
 
-            def fn(params, batch, ctx, logits_idx):
+            def suffix_prefill(params, batch, ctx, logits_idx):
                 cache = m.init_cache(n_seqs, bl)
                 return m.prefill_suffix(params, batch, cache, ctx, offset,
                                         logits_at=logits_idx)
-            self._jits[key] = self._jit(fn)
+            self._jits[key] = self._jit(suffix_prefill)
         return self._jits[key]
 
     def _chunk_fn(self, n_tokens: int):
@@ -469,10 +469,10 @@ class ServingEngine:
         if key not in self._jits:
             m, ml, greedy = self.model, self.max_len, self.greedy
 
-            def fn(params, cache, state):
+            def decode_chunk(params, cache, state):
                 return m.decode_chunk(params, cache, state, n_tokens,
                                       max_len=ml, greedy=greedy)
-            self._jits[key] = self._jit(fn, donate_argnums=(1,))
+            self._jits[key] = self._jit(decode_chunk, donate_argnums=(1,))
         return self._jits[key]
 
     def _insert_rows(self, src_cache: Any, slot_ids: list[int]) -> None:
@@ -503,15 +503,16 @@ class ServingEngine:
         return take
 
     def _admit(self) -> None:
-        if self.paged:
-            self._admit_paged()
-            return
-        free = [i for i, s in enumerate(self.slots) if not s.active]
-        while free and self.queue:
-            reqs = (self._take_bucket(len(free)) if self.batch_admit
-                    else [self.queue.popleft()])
-            slot_ids = [free.pop(0) for _ in reqs]
-            self._admit_batch(slot_ids, reqs)
+        with TraceAnnotation("engine.admit"):
+            if self.paged:
+                self._admit_paged()
+                return
+            free = [i for i, s in enumerate(self.slots) if not s.active]
+            while free and self.queue:
+                reqs = (self._take_bucket(len(free)) if self.batch_admit
+                        else [self.queue.popleft()])
+                slot_ids = [free.pop(0) for _ in reqs]
+                self._admit_batch(slot_ids, reqs)
 
     def _cache_tokens(self, req: Request) -> int:
         """Cache positions a request can ever touch: vision prefix +
@@ -642,49 +643,51 @@ class ServingEngine:
         n = len(reqs)
         nv = self.model.cfg.n_vision_tokens or 0
         H = plans[0][0] if plans and plans[0] is not None else 0
-        if H:
-            # residual-suffix prefill: every row shares hit length H (in
-            # the admit key), so one gathered context of width exactly H
-            # serves the batch. Gather BEFORE insert — insert donates the
-            # tree the gather reads.
-            bl = _bucket(nv + len(reqs[0].prompt) - H)
-            padded = np.zeros((n, bl), np.int32)
-            logits_idx = np.zeros((n,), np.int32)
-            for j, r in enumerate(reqs):
-                sfx = np.asarray(r.prompt)[H - nv:]
-                padded[j, :len(sfx)] = sfx
-                logits_idx[j] = len(sfx) - 1
-            batch = {"tokens": jnp.asarray(padded)}
-            ctx = self.cache_backend.gather_prefix(slot_ids, H)
-            logits, src_cache = self._suffix_prefill_fn(n, bl, H)(
-                self.params, batch, ctx, jnp.asarray(logits_idx))
-            self.cache_backend.insert(src_cache, slot_ids, offset=H)
-            self.prefill_tokens_executed += sum(
-                nv + len(r.prompt) - H for r in reqs)
-            self.prefix_hit_tokens_total += n * H
-        else:
-            bl, _ = self._admit_key(reqs[0])
-            padded = np.zeros((n, bl), np.int32)
-            logits_idx = np.zeros((n,), np.int32)
-            for j, r in enumerate(reqs):
-                plen = len(r.prompt)
-                padded[j, :plen] = r.prompt   # right-pad into the bucket
-                logits_idx[j] = nv + plen - 1
-            batch = {"tokens": jnp.asarray(padded)}
-            for k in reqs[0].extras:
-                batch[k] = jnp.asarray(np.stack([np.asarray(r.extras[k])
-                                                 for r in reqs]))
-            logits, src_cache = self._prefill_fn(n, bl)(
-                self.params, batch, jnp.asarray(logits_idx))
-            self._insert_rows(src_cache, slot_ids)
-            self.prefill_tokens_executed += sum(
-                nv + len(r.prompt) for r in reqs)
-        if self._share and plans:
-            # index the new rows' full prompt blocks (hit rows extend the
-            # chain past their hit; already-indexed hashes are skipped)
-            for i, pl in zip(slot_ids, plans):
-                self.cache_backend.register_prefix(i, pl[2])
-        first = self._pick(logits)
+        bl = (_bucket(nv + len(reqs[0].prompt) - H) if H
+              else self._admit_key(reqs[0])[0])
+        with TraceAnnotation("engine.prefill", rows=n, bucket=bl,
+                             hit_tokens=H):
+            if H:
+                # residual-suffix prefill: every row shares hit length H (in
+                # the admit key), so one gathered context of width exactly H
+                # serves the batch. Gather BEFORE insert — insert donates the
+                # tree the gather reads.
+                padded = np.zeros((n, bl), np.int32)
+                logits_idx = np.zeros((n,), np.int32)
+                for j, r in enumerate(reqs):
+                    sfx = np.asarray(r.prompt)[H - nv:]
+                    padded[j, :len(sfx)] = sfx
+                    logits_idx[j] = len(sfx) - 1
+                batch = {"tokens": jnp.asarray(padded)}
+                ctx = self.cache_backend.gather_prefix(slot_ids, H)
+                logits, src_cache = self._suffix_prefill_fn(n, bl, H)(
+                    self.params, batch, ctx, jnp.asarray(logits_idx))
+                self.cache_backend.insert(src_cache, slot_ids, offset=H)
+                self.prefill_tokens_executed += sum(
+                    nv + len(r.prompt) - H for r in reqs)
+                self.prefix_hit_tokens_total += n * H
+            else:
+                padded = np.zeros((n, bl), np.int32)
+                logits_idx = np.zeros((n,), np.int32)
+                for j, r in enumerate(reqs):
+                    plen = len(r.prompt)
+                    padded[j, :plen] = r.prompt   # right-pad into the bucket
+                    logits_idx[j] = nv + plen - 1
+                batch = {"tokens": jnp.asarray(padded)}
+                for k in reqs[0].extras:
+                    batch[k] = jnp.asarray(np.stack([np.asarray(r.extras[k])
+                                                     for r in reqs]))
+                logits, src_cache = self._prefill_fn(n, bl)(
+                    self.params, batch, jnp.asarray(logits_idx))
+                self._insert_rows(src_cache, slot_ids)
+                self.prefill_tokens_executed += sum(
+                    nv + len(r.prompt) for r in reqs)
+            if self._share and plans:
+                # index the new rows' full prompt blocks (hit rows extend the
+                # chain past their hit; already-indexed hashes are skipped)
+                for i, pl in zip(slot_ids, plans):
+                    self.cache_backend.register_prefix(i, pl[2])
+            first = self._pick(logits)
         now = time.perf_counter()
         for j, (i, r) in enumerate(zip(slot_ids, reqs)):
             slot = self.slots[i]
@@ -786,38 +789,42 @@ class ServingEngine:
         # clamp value (ragged budgets would otherwise trigger a compile
         # spike mid-serving on each new length)
         n_tokens = 1 << (exact.bit_length() - 1)
-        n_rows = len(self.slots)
-        tok = np.zeros((n_rows,), np.int32)
-        pos = np.zeros((n_rows,), np.int32)
-        rem = np.zeros((n_rows,), np.int32)
-        act = np.zeros((n_rows,), bool)
-        for i in active:
-            s = self.slots[i]
-            tok[i], pos[i], rem[i], act[i] = (s.generated[-1], s.pos,
-                                              s.remaining, True)
-        state = {"tokens": jnp.asarray(tok), "pos": jnp.asarray(pos),
-                 "remaining": jnp.asarray(rem), "active": jnp.asarray(act),
-                 "key": self._key}
-        block, emitted, state, self.cache = self._chunk_fn(n_tokens)(
-            self.params, self.cache, state)
-        self._key = state["key"]
-        block, emitted = jax.device_get((block, emitted))
+        with TraceAnnotation("engine.decode", rows=len(active),
+                             tokens=n_tokens):
+            n_rows = len(self.slots)
+            tok = np.zeros((n_rows,), np.int32)
+            pos = np.zeros((n_rows,), np.int32)
+            rem = np.zeros((n_rows,), np.int32)
+            act = np.zeros((n_rows,), bool)
+            for i in active:
+                s = self.slots[i]
+                tok[i], pos[i], rem[i], act[i] = (s.generated[-1], s.pos,
+                                                  s.remaining, True)
+            state = {"tokens": jnp.asarray(tok), "pos": jnp.asarray(pos),
+                     "remaining": jnp.asarray(rem),
+                     "active": jnp.asarray(act), "key": self._key}
+            block, emitted, state, self.cache = self._chunk_fn(n_tokens)(
+                self.params, self.cache, state)
+            self._key = state["key"]
+        with TraceAnnotation("engine.readback"):
+            block, emitted = jax.device_get((block, emitted))
         now = time.perf_counter()
-        for i in active:
-            s = self.slots[i]
-            c = int(emitted[i])
-            new = block[i, :c].tolist()
-            s.generated.extend(new)
-            s.pos += c
-            s.remaining -= c
-            self.tokens_generated += c
-            if new:
-                # one ChunkEvent per request per macro-step, built from
-                # the block that the single host transfer above already
-                # materialised — streaming costs no extra syncs
-                self._emit_chunk(s.rid, new, now)
-            if s.remaining <= 0 or s.pos >= self.max_len - 1:
-                self._finish(i)
+        with TraceAnnotation("engine.emit"):
+            for i in active:
+                s = self.slots[i]
+                c = int(emitted[i])
+                new = block[i, :c].tolist()
+                s.generated.extend(new)
+                s.pos += c
+                s.remaining -= c
+                self.tokens_generated += c
+                if new:
+                    # one ChunkEvent per request per macro-step, built from
+                    # the block that the single host transfer above already
+                    # materialised — streaming costs no extra syncs
+                    self._emit_chunk(s.rid, new, now)
+                if s.remaining <= 0 or s.pos >= self.max_len - 1:
+                    self._finish(i)
         self.chunks += 1
 
     def _decode_token(self, active: list[int]) -> None:
@@ -854,19 +861,20 @@ class ServingEngine:
         including admit-only ones — counts against ``run``'s budget."""
         if not self.has_work:
             return False
-        self.steps += 1
-        if self.fault is not None:
-            self.fault.on_step(self.steps)   # may raise InjectedFault
-        t0 = time.perf_counter()
-        self._expire_deadlines()
-        self._admit()
-        active = [i for i, s in enumerate(self.slots) if s.active]
-        if active:
-            if self.chunked:
-                self._decode_chunk(active)
-            else:
-                self._decode_token(active)
-        self.busy_s += time.perf_counter() - t0
+        with TraceAnnotation("engine.step"):
+            self.steps += 1
+            if self.fault is not None:
+                self.fault.on_step(self.steps)   # may raise InjectedFault
+            t0 = time.perf_counter()
+            self._expire_deadlines()
+            self._admit()
+            active = [i for i, s in enumerate(self.slots) if s.active]
+            if active:
+                if self.chunked:
+                    self._decode_chunk(active)
+                else:
+                    self._decode_token(active)
+            self.busy_s += time.perf_counter() - t0
         return self.has_work
 
     def run(self, max_steps: int = 10_000) -> list[Completion]:

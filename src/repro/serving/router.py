@@ -74,6 +74,8 @@ import time
 from collections import Counter, defaultdict, deque
 from typing import Any, Callable, Iterator, Sequence
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.scheduler import DivideAndSaveScheduler
 from repro.serving.engine import Completion, Request, _bucket
 from repro.serving.events import (ChunkEvent, ContainerFailure, DoneEvent,
@@ -565,7 +567,16 @@ class Router:
         requests) and the router-side deadline backstop. With ``block``
         and nothing to route, naps briefly so process-backend waits
         don't spin."""
-        events = self.backend.poll()
+        with TraceAnnotation("router.poll"):
+            events = self.backend.poll()
+            self._route(events)
+        if block and not events:
+            time.sleep(_IDLE_SLEEP_S)
+        return events
+
+    def _route(self, events: list[Event]) -> None:
+        """Route one poll's events to their handles, then run the
+        deadline backstop, the SLO backlog and the window rotation."""
         now = time.perf_counter()
         for ev in events:
             if isinstance(ev, ContainerFailure):
@@ -606,9 +617,6 @@ class Router:
             self._drain_backlog()
         if self.scheduler is not None:
             self._maybe_rotate_window()
-        if block and not events:
-            time.sleep(_IDLE_SLEEP_S)
-        return events
 
     def poll(self) -> list[Event]:
         """Public pump: advance containers, route events, return the
