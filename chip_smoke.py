@@ -190,7 +190,8 @@ def check_kernels(cfg, dense_eng, paged_eng, seed, problems):
 
     P, bs = paged_eng.cache["stack"]["k_pages"].shape[1:3]
     B, nblk = paged_eng.cache["stack"]["table"].shape[1:3]
-    qp, kp, vp = normal(B, H, K), normal(P, bs, Hkv, K), normal(P, bs, Hkv, K)
+    qp, kp, vp = (normal(B, H, K), normal(P, bs, Hkv * K),
+                  normal(P, bs, Hkv * K))
     table = jax.random.randint(next(keys), (B, nblk), 0, P)
     plens = jax.random.randint(next(keys), (B,), 1, nblk * bs + 1)
     cases.append((f"paged_decode_attention (B={B}, pages={P}, "

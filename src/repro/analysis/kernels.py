@@ -19,7 +19,8 @@ shared jit cache. The captured spec is then checked:
 * VMEM budget — double-buffered block tiles plus scratch must fit the
   per-core VMEM (~16 MiB on current TPUs; the guide's figure);
 * dtype consistency — scratch accumulators must be f32, and int8 page
-  operands must travel with f32 scale operands (the dequant contract).
+  operands must travel with f32 scale operands, one per head_dim run
+  of int8 lanes (the dequant contract).
 """
 from __future__ import annotations
 
@@ -225,13 +226,15 @@ def check_spec(spec: KernelSpec,
     int8_ops = [i for i, av in enumerate(spec.operands)
                 if np.dtype(av.dtype) == np.int8]
     if int8_ops:
-        # one f32 scale per int8 row: the int8 operand's size over its
-        # last (head) dim, in whatever layout the kernel reads it
+        # one f32 scale per run of head_dim int8 values in the last
+        # (lane) dim — a row of (…, hd) or one head of (…, Hkv·hd) — in
+        # whatever layout the kernel reads it
         q8 = spec.operands[int8_ops[0]].shape
-        rows = int(np.prod(q8[:-1]))
+        n8 = int(np.prod(q8))
         scales = [av for av in spec.operands
                   if np.dtype(av.dtype) == np.float32
-                  and int(np.prod(av.shape)) == rows]
+                  and n8 % int(np.prod(av.shape)) == 0
+                  and q8[-1] % (n8 // int(np.prod(av.shape))) == 0]
         if int8_scales_expected and not scales:
             findings.append(Finding(
                 "kernels", "KRN008", site,
@@ -282,7 +285,7 @@ def run() -> list[Finding]:
     from repro.kernels.ssd_scan import ssd_scan
 
     findings: list[Finding] = []
-    P, bs, nblk = 9, 16, 4          # 8 live pages + scratch
+    L, P, bs, nblk = 3, 9, 16, 4    # 3 layers of 8 live pages + scratch
 
     cases: list[tuple[str, Any, tuple, dict, dict, bool]] = [
         # (name, fn, args, static kwargs, table_fill, int8)
@@ -303,16 +306,16 @@ def run() -> list[Finding]:
          dict(softcap=0.0, block_k=256, interpret=False), {}, True),
         ("paged_decode_attention", paged_decode_attention,
          (_f32(2, 4, 128),
-          _f32(P, bs, 2, 128), _f32(P, bs, 2, 128),
-          _i32(2, nblk), _i32(2)),
-         dict(softcap=0.0, interpret=False), {0: P - 1}, False),
+          _f32(L, P, bs, 2 * 128), _f32(L, P, bs, 2 * 128),
+          _i32(2, nblk), _i32(2), _i32()),
+         dict(softcap=0.0, interpret=False), {0: P - 1, 2: L - 1}, False),
         ("paged_decode_attention_int8", paged_decode_attention_int8,
          (_f32(2, 4, 128),
-          jax.ShapeDtypeStruct((P, bs, 2, 128), jnp.int8),
-          jax.ShapeDtypeStruct((P, bs, 2, 128), jnp.int8),
-          _f32(P, bs, 2), _f32(P, bs, 2),
-          _i32(2, nblk), _i32(2)),
-         dict(softcap=0.0, interpret=False), {0: P - 1}, True),
+          jax.ShapeDtypeStruct((L, P, bs, 2 * 128), jnp.int8),
+          jax.ShapeDtypeStruct((L, P, bs, 2 * 128), jnp.int8),
+          _f32(L, P, bs, 2), _f32(L, P, bs, 2),
+          _i32(2, nblk), _i32(2), _i32()),
+         dict(softcap=0.0, interpret=False), {0: P - 1, 2: L - 1}, True),
         ("mla_decode_ctx", mla_decode_ctx,
          (_f32(2, 4, 256), _f32(2, 4, 64), _f32(2, 1024, 256),
           _f32(2, 1024, 64),

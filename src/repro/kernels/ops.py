@@ -59,26 +59,29 @@ def decode_attention(q, k, v, valid, *, softcap: float = 0.0,
                                 interpret=(mode == "interpret"))
 
 
-def paged_decode_attention(q, k_pages, v_pages, table, lengths, *,
+def paged_decode_attention(q, k_pages, v_pages, table, lengths, layer=0, *,
                            softcap: float = 0.0, k_scale_pages=None,
                            v_scale_pages=None):
     """Paged flash-decode: K/V gathered through a per-sequence block table
-    over a shared physical page pool. q: (B, H, hd); pages:
-    (P, block_size, Hkv, hd); table: (B, nblk) int32; lengths: (B,).
-    Optional scale pages mean int8 pages (dequant in VMEM)."""
+    over a shared physical page pool, read in place. q: (B, H, hd);
+    pages: (L, P, block_size, Hkv·hd), or one layer's (P, block_size,
+    Hkv·hd); table: (B, nblk) int32; lengths: (B,); layer: the stack
+    index read. Optional scale pages (L, P, block_size, Hkv) mean int8
+    pages (dequant in VMEM)."""
     mode = _mode()
     if mode == "ref":
         return ref.paged_decode_attention(q, k_pages, v_pages, table,
-                                          lengths, softcap=softcap,
+                                          lengths, layer, softcap=softcap,
                                           k_scale_pages=k_scale_pages,
                                           v_scale_pages=v_scale_pages)
     from repro.kernels import paged_attention as _pa
     if k_scale_pages is not None:
         return _pa.paged_decode_attention_int8(
             q, k_pages, v_pages, k_scale_pages, v_scale_pages, table,
-            lengths, softcap=softcap, interpret=(mode == "interpret"))
+            lengths, layer, softcap=softcap,
+            interpret=(mode == "interpret"))
     return _pa.paged_decode_attention(q, k_pages, v_pages, table, lengths,
-                                      softcap=softcap,
+                                      layer, softcap=softcap,
                                       interpret=(mode == "interpret"))
 
 

@@ -167,33 +167,40 @@ def decode_attention_blocked(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, table: jax.Array,
-                           lengths: jax.Array, *, softcap: float = 0.0,
+                           lengths: jax.Array, layer: jax.Array | int = 0,
+                           *, softcap: float = 0.0,
                            k_scale_pages: jax.Array | None = None,
                            v_scale_pages: jax.Array | None = None
                            ) -> jax.Array:
     """Paged flash-decode oracle: gather K/V through a per-sequence block
     table, then run the SAME blocked online softmax as the dense path.
 
-    q: (B, H, K); k_pages/v_pages: (P, bs, Hkv, K) — a shared physical
-    page pool (the last page is conventionally scratch); table: (B, nblk)
-    int32 page indices per logical block; lengths: (B,) — tokens [0, len)
-    are live. Masked positions (scratch garbage included) contribute an
-    exact 0.0 to the accumulator, so for equal live prefixes the output is
-    bitwise identical to ``decode_attention_blocked`` over a dense
-    (B, nblk*bs) cache — the bit-parity contract the paged serving engine
-    tests pin down.
+    q: (B, H, K); k_pages/v_pages: (L, P, bs, Hkv·K) — a stack of shared
+    physical page pools, token-major with the kv heads in the lanes (the
+    last page is conventionally scratch) — or one layer's (P, bs, Hkv·K);
+    ``layer`` picks the stack entry; table: (B, nblk) int32 page indices
+    per logical block; lengths: (B,) — tokens [0, len) are live; scale
+    pages (int8 K/V): (L, P, bs, Hkv) or (P, bs, Hkv). Masked positions
+    (scratch garbage included) contribute an exact 0.0 to the
+    accumulator, so for equal live prefixes the output is bitwise
+    identical to ``decode_attention_blocked`` over a dense (B, nblk*bs)
+    cache — the bit-parity contract the paged serving engine tests pin
+    down.
     """
-    B = q.shape[0]
+    def pool(pages, ndim):
+        return pages if pages.ndim == ndim else pages[layer]
+    B, _, K = q.shape
+    kp, vp = pool(k_pages, 3), pool(v_pages, 3)
     nblk = table.shape[1]
-    bs = k_pages.shape[1]
+    bs, D = kp.shape[1], kp.shape[2]
     W = nblk * bs
-    k = k_pages[table].reshape(B, W, *k_pages.shape[2:])
-    v = v_pages[table].reshape(B, W, *v_pages.shape[2:])
+    k = kp[table].reshape(B, W, D // K, K)
+    v = vp[table].reshape(B, W, D // K, K)
     valid = jnp.arange(W)[None, :] < lengths[:, None]
     ks = vs = None
     if k_scale_pages is not None:
-        ks = k_scale_pages[table].reshape(B, W, k_scale_pages.shape[2])
-        vs = v_scale_pages[table].reshape(B, W, v_scale_pages.shape[2])
+        ks = pool(k_scale_pages, 3)[table].reshape(B, W, D // K)
+        vs = pool(v_scale_pages, 3)[table].reshape(B, W, D // K)
     return decode_attention_blocked(q, k, v, valid, softcap=softcap,
                                     k_scale=ks, v_scale=vs)
 

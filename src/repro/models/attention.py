@@ -233,37 +233,38 @@ def attn_decode(p: dict, cfg: ArchConfig, x: jax.Array, cache: dict,
     q, k, v = _qkv(p, cfg, x, positions)
     bidx = jnp.arange(B)
     if "k_pages" in cache:
-        # paged layout: write the new token into its block-table page
-        # (idle rows point at the scratch page) and run the paged
-        # flash-decode gather. Only full-horizon layers are paged, so
-        # slot == position and validity is simply position < pos+1 —
-        # the same mask the dense ring produces when W == max_len.
-        bs = cache["k_pages"].shape[1]
-        pidx = cache["table"][bidx, pos // bs]               # (B,)
+        # paged layout: the group's page pools are the whole layer stack
+        # (L, P+1, bs, Hkv·hd), carried by the model's layer scan, with
+        # this layer's index under "layer" (model._layer_scan). The new
+        # token's row is written in place at [layer, page, off] (idle
+        # rows point at the scratch page) and the kernel reads the same
+        # stack. Only full-horizon layers are paged, so slot == position
+        # and validity is simply position < pos+1 — the same mask the
+        # dense ring produces when W == max_len.
+        layer, table = cache["layer"], cache["table"]
+        bs = cache["k_pages"].shape[-2]
+        pidx = table[bidx, pos // bs]                        # (B,)
         off = jnp.mod(pos, bs)
         lengths = (pos + 1).astype(jnp.int32)
         if cfg.kv_cache_dtype == "int8":
             kq, ks = _quant_kv(k[:, 0])
             vq, vs = _quant_kv(v[:, 0])
-            kp = cache["k_pages"].at[pidx, off].set(kq)
-            vp = cache["v_pages"].at[pidx, off].set(vq)
-            ksp = cache["k_scale_pages"].at[pidx, off].set(ks)
-            vsp = cache["v_scale_pages"].at[pidx, off].set(vs)
-            out = kops.paged_decode_attention(
-                q[:, 0], kp, vp, cache["table"], lengths,
-                softcap=cfg.attn_logit_softcap,
-                k_scale_pages=ksp, v_scale_pages=vsp)
-            y = jnp.einsum("bhk,hkd->bd", out, p["wo"])[:, None]
-            return y, {"table": cache["table"], "k_pages": kp,
-                       "v_pages": vp, "k_scale_pages": ksp,
-                       "v_scale_pages": vsp}
-        kp = cache["k_pages"].at[pidx, off].set(k[:, 0])
-        vp = cache["v_pages"].at[pidx, off].set(v[:, 0])
-        out = kops.paged_decode_attention(q[:, 0], kp, vp, cache["table"],
-                                          lengths,
-                                          softcap=cfg.attn_logit_softcap)
+            rows = {"k_pages": kq, "v_pages": vq,
+                    "k_scale_pages": ks, "v_scale_pages": vs}
+        else:
+            rows = {"k_pages": k[:, 0], "v_pages": v[:, 0]}
+        new = dict(cache)
+        for name, row in rows.items():
+            pages = cache[name]
+            new[name] = pages.at[layer, pidx, off].set(
+                row.reshape((B,) + pages.shape[3:]))
+        out = kops.paged_decode_attention(
+            q[:, 0], new["k_pages"], new["v_pages"], table, lengths, layer,
+            softcap=cfg.attn_logit_softcap,
+            k_scale_pages=new.get("k_scale_pages"),
+            v_scale_pages=new.get("v_scale_pages"))
         y = jnp.einsum("bhk,hkd->bd", out, p["wo"])[:, None]
-        return y, {"table": cache["table"], "k_pages": kp, "v_pages": vp}
+        return y, new
     W = cache["k"].shape[1]
     slot = jnp.mod(pos, W)                                   # (B,)
     valid = _ring_positions(W, pos) >= 0                     # (B, W)
@@ -320,14 +321,17 @@ def attn_suffix_prefill_into_cache(p: dict, cfg: ArchConfig, x: jax.Array,
     absolute rope positions, keys/values are [cached prefix, suffix].
     Causal masking right-aligns queries against the key axis, so the
     context width must equal ``offset`` EXACTLY — padding belongs on the
-    suffix side only. Returns the suffix K/V as the mini-cache (width ==
-    S: the whole ring is the suffix). Full-horizon rope attention only —
+    suffix side only. ``ctx_k``/``ctx_v`` are (B, offset, Hkv·hd) as
+    read from the pages. Returns the suffix K/V as the mini-cache (width
+    == S: the whole ring is the suffix). Full-horizon rope attention only —
     the engine's sharing gate excludes windows, MLA and int8 caches."""
     B, S, _ = x.shape
     positions = offset + jnp.arange(S)[None, :]
     q, k, v = _qkv(p, cfg, x, positions)
-    ck = jnp.concatenate([ctx_k.astype(k.dtype), k], axis=1)
-    cv = jnp.concatenate([ctx_v.astype(v.dtype), v], axis=1)
+    ck = jnp.concatenate([ctx_k.reshape(k.shape[:1] + (-1,) + k.shape[2:])
+                          .astype(k.dtype), k], axis=1)
+    cv = jnp.concatenate([ctx_v.reshape(v.shape[:1] + (-1,) + v.shape[2:])
+                          .astype(v.dtype), v], axis=1)
     out = kops.flash_attention(q, ck, cv, causal=True, window=0,
                                softcap=cfg.attn_logit_softcap)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])

@@ -11,14 +11,21 @@ Per-layer group shapes (the model stacks layers on top, exactly like the
 dense constructors in attention.py):
 
   attention:  ``{"table": (B, nblk) int32,
-                 "k_pages"/"v_pages": (P+1, block_size, Hkv, hd)}``
+                 "k_pages"/"v_pages": (P+1, block_size, Hkv·hd)}``
               (+ ``k_scale_pages``/``v_scale_pages`` (P+1, bs, Hkv) f32
               for an int8 cache)
   MLA:        ``{"table": (B, nblk) int32,
                  "ckv_pages": (P+1, bs, kv_lora_rank),
                  "k_rope_pages": (P+1, bs, qk_rope_head_dim)}``
 
-with ``nblk = max_len // block_size`` and ``P = max_blocks``. Page index
+with ``nblk = max_len // block_size`` and ``P = max_blocks``. Attention
+pages are token-major with the kv heads flattened into the lanes: one
+kv head's ``hd`` values are a lane block of a page, so the paged decode
+kernel reads a stacked ``(L, P+1, bs, Hkv·hd)`` pool where it lies and
+the decode step writes a token's row in place (a 5-D ``(…, Hkv, hd)``
+page would need a relayout copy of the pool on the TPU's (8, 128)
+tiling). Programs that move pages to and from the dense per-request
+``(B, W, Hkv, hd)`` side reshape that side, never the pool. Page index
 ``P`` (the last page) is SCRATCH: unreserved table entries point at it,
 so lockstep decode writes for idle/finished rows land there instead of
 corrupting live sequences. Attention never reads garbage — validity is
@@ -83,15 +90,15 @@ def init_paged_attn_cache(cfg: ArchConfig, batch: int, max_len: int, dtype,
     if cfg.kv_cache_dtype == "int8":
         return {
             "table": table,
-            "k_pages": jnp.zeros((P + 1, bs, kv, hd), jnp.int8),
-            "v_pages": jnp.zeros((P + 1, bs, kv, hd), jnp.int8),
+            "k_pages": jnp.zeros((P + 1, bs, kv * hd), jnp.int8),
+            "v_pages": jnp.zeros((P + 1, bs, kv * hd), jnp.int8),
             "k_scale_pages": jnp.zeros((P + 1, bs, kv), jnp.float32),
             "v_scale_pages": jnp.zeros((P + 1, bs, kv), jnp.float32),
         }
     return {
         "table": table,
-        "k_pages": jnp.zeros((P + 1, bs, kv, hd), dtype),
-        "v_pages": jnp.zeros((P + 1, bs, kv, hd), dtype),
+        "k_pages": jnp.zeros((P + 1, bs, kv * hd), dtype),
+        "v_pages": jnp.zeros((P + 1, bs, kv * hd), dtype),
     }
 
 

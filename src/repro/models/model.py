@@ -18,6 +18,7 @@ Caches mirror the param tree; decode positions are per-sequence ``(B,)``
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import jax
@@ -67,6 +68,86 @@ def _sinusoid(positions: jax.Array, d: int) -> jax.Array:
 
 def _stacked_init(init_fn, key, n: int):
     return jax.vmap(init_fn)(jax.random.split(key, n))
+
+
+# page pools of a paged attention group (models/cache.py)
+_POOLS = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
+
+
+def _pool_groups(tree, path=()) -> list[tuple]:
+    """Paths of the paged attention groups in a cache subtree."""
+    if not isinstance(tree, dict):
+        return []
+    if "k_pages" in tree:
+        return [path]
+    return [q for k, v in tree.items() for q in _pool_groups(v, path + (k,))]
+
+
+def _at(tree, path):
+    for k in path:
+        tree = tree[k]
+    return tree
+
+
+def _put(tree, path, value):
+    if not path:
+        return value
+    return {**tree, path[0]: _put(tree[path[0]], path[1:], value)}
+
+
+def _without_pools(group: dict) -> dict:
+    return {k: v for k, v in group.items()
+            if k not in _POOLS and k != "layer"}
+
+
+def _layer_scan(step, x, params, cache):
+    """``lax.scan`` of ``step(h, p, c) -> (h, c)`` over a decode layer
+    stack, with the page pools of every paged attention group in the
+    CARRY instead of xs/ys. ``step`` sees each group's whole pool,
+    flattened to ``(layers, P+1, bs, ·)``, and its own layer's index
+    under ``"layer"``; attention writes its token there in place and the
+    kernel reads the pool where it lies. In xs/ys the scan would slice
+    each layer's pool out and stack it back: two copies of the pool per
+    layer per token. A group that an enclosing scan already carries (the
+    inner stack of a super-block) keeps its flat pool, and its layers
+    are indexed from the ``"layer"`` it was given."""
+    paths = _pool_groups(cache)
+    n = jax.tree.leaves(params)[0].shape[0]
+    pools, base, stride, xs_cache = {}, {}, {}, cache
+    for path in paths:
+        g = _at(cache, path)
+        sdims = g["table"].ndim - 2             # stack axes, this scan's first
+        stride[path] = math.prod(g["table"].shape[1:sdims])
+        base[path] = g.get("layer", 0)
+        pools[path] = {k: (g[k] if "layer" in g else
+                           g[k].reshape((-1,) + g[k].shape[sdims:]))
+                       for k in _POOLS if k in g}
+        xs_cache = _put(xs_cache, path, _without_pools(g))
+
+    def body(carry, xs):
+        h, pools = carry
+        p, c, i = xs
+        for path in paths:
+            c = _put(c, path, {**_at(c, path), **pools[path],
+                               "layer": base[path] + i * stride[path]})
+        h, c = step(h, p, c)
+        new = {}
+        for path in paths:
+            g = _at(c, path)
+            new[path] = {k: g[k] for k in _POOLS if k in g}
+            c = _put(c, path, _without_pools(g))
+        return (h, new), c
+
+    (x, pools), ys = jax.lax.scan(body, (x, pools),
+                                  (params, xs_cache, jnp.arange(n)))
+    for path in paths:
+        g = _at(cache, path)
+        if "layer" in g:
+            out = {**pools[path], "layer": g["layer"]}
+        else:
+            out = {k: v.reshape(g[k].shape) for k, v in pools[path].items()}
+        ys = _put(ys, path, {**_at(ys, path), **out})
+    return x, ys
 
 
 class Model:
@@ -466,7 +547,8 @@ class Model:
         """Prefill only the residual suffix of prompts whose first
         ``offset`` positions are prefix-cache hits: ``ctx`` mirrors the
         cache tree with per-group ``{"k", "v"}`` context of width exactly
-        ``offset`` (gathered from the shared pages), ``batch["tokens"]``
+        ``offset`` (gathered from the shared pages, ``(…, B, offset,
+        Hkv·hd)``), ``batch["tokens"]``
         holds the suffix tokens, and the returned mini-cache covers the
         suffix positions only (``insert`` lands it at ``offset``).
         Restricted to the sharing-eligible families — dense/moe, non-MLA,
@@ -514,79 +596,54 @@ class Model:
             x = x + _sinusoid(pos[:, None], cfg.d_model).astype(x.dtype)
         new_cache: dict = {}
         if fam == "whisper":
-            def step(carry, pc):
-                p, c = pc
-                h, nc = blocks.cross_decode(p, cfg, carry, c, pos)
-                return h, nc
-            x, ns = jax.lax.scan(step, x, (params["stack"], cache["stack"]))
+            x, ns = _layer_scan(
+                lambda h, p, c: blocks.cross_decode(p, cfg, h, c, pos), x,
+                params["stack"], cache["stack"])
             return self._head(params, x[:, -1]), {"stack": ns}
+
+        def attn_mlp(h, p, c):
+            return blocks.attn_mlp_decode(p, cfg, h, c, pos)
+
+        def ssm(h, p, c):
+            return blocks.ssm_decode(p, cfg, h, c, pos)
 
         if fam in ("dense", "moe"):
             if fam == "moe" and self.n_dense:
-                def d_step(carry, pc):
-                    p, c = pc
-                    h, nc = blocks.attn_mlp_decode(p, cfg, carry, c, pos)
-                    return h, nc
-                x, nd = jax.lax.scan(d_step, x, (params["dense0"],
-                                                 cache["dense0"]))
-                new_cache["dense0"] = nd
+                x, new_cache["dense0"] = _layer_scan(
+                    attn_mlp, x, params["dense0"], cache["dense0"])
             fwd = (blocks.attn_moe_decode if fam == "moe"
                    else blocks.attn_mlp_decode)
-
-            def step(carry, pc):
-                p, c = pc
-                h, nc = fwd(p, cfg, carry, c, pos)
-                return h, nc
-            x, ns = jax.lax.scan(step, x, (params["stack"], cache["stack"]))
-            new_cache["stack"] = ns
+            x, new_cache["stack"] = _layer_scan(
+                lambda h, p, c: fwd(p, cfg, h, c, pos), x, params["stack"],
+                cache["stack"])
         elif fam == "gemma":
-            def super_step(carry, pc):
-                p, c = pc
-
-                def l_step(cc, plc):
-                    pl_, cl = plc
-                    return blocks.attn_mlp_decode(pl_, cfg, cc, cl, pos)
-                h, nl = jax.lax.scan(l_step, carry, (p["local"], c["local"]))
-                h, ng = blocks.attn_mlp_decode(p["global"], cfg, h,
-                                               c["global"], pos)
+            def super_step(h, p, c):
+                h, nl = _layer_scan(attn_mlp, h, p["local"], c["local"])
+                h, ng = attn_mlp(h, p["global"], c["global"])
                 return h, {"local": nl, "global": ng}
-            x, nsuper = jax.lax.scan(super_step, x,
-                                     (params["super"], cache["super"]))
-            new_cache["super"] = nsuper
+            x, new_cache["super"] = _layer_scan(super_step, x,
+                                                params["super"],
+                                                cache["super"])
             if self.n_rem:
-                def r_step(cc, plc):
-                    pl_, cl = plc
-                    return blocks.attn_mlp_decode(pl_, cfg, cc, cl, pos)
-                x, nr = jax.lax.scan(r_step, x, (params["rem"], cache["rem"]))
-                new_cache["rem"] = nr
+                x, new_cache["rem"] = _layer_scan(attn_mlp, x,
+                                                  params["rem"],
+                                                  cache["rem"])
         elif fam == "ssm":
-            def step(carry, pc):
-                p, c = pc
-                return blocks.ssm_decode(p, cfg, carry, c, pos)
-            x, ns = jax.lax.scan(step, x, (params["stack"], cache["stack"]))
-            new_cache["stack"] = ns
+            x, new_cache["stack"] = _layer_scan(ssm, x, params["stack"],
+                                                cache["stack"])
         elif fam == "zamba":
             shared = params["shared"]
 
-            def super_step(carry, pc):
-                p, c = pc
-
-                def s_step(cc, psc):
-                    ps, cs = psc
-                    return blocks.ssm_decode(ps, cfg, cc, cs, pos)
-                h, nssm = jax.lax.scan(s_step, carry, (p["ssm"], c["ssm"]))
-                h, nsh = blocks.attn_mlp_decode(shared, cfg, h, c["shared"],
-                                                pos)
+            def super_step(h, p, c):
+                h, nssm = _layer_scan(ssm, h, p["ssm"], c["ssm"])
+                h, nsh = attn_mlp(h, shared, c["shared"])
                 return h, {"ssm": nssm, "shared": nsh}
-            x, nsuper = jax.lax.scan(super_step, x,
-                                     (params["super"], cache["super"]))
-            new_cache["super"] = nsuper
+            x, new_cache["super"] = _layer_scan(super_step, x,
+                                                params["super"],
+                                                cache["super"])
             if self.n_rem:
-                def r_step(cc, psc):
-                    ps, cs = psc
-                    return blocks.ssm_decode(ps, cfg, cc, cs, pos)
-                x, nr = jax.lax.scan(r_step, x, (params["rem"], cache["rem"]))
-                new_cache["rem"] = nr
+                x, new_cache["rem"] = _layer_scan(ssm, x, params["rem"],
+                                                  cache["rem"])
         else:
             raise ValueError(fam)
         return self._head(params, x[:, -1]), new_cache
@@ -620,8 +677,12 @@ class Model:
 
         Returns ``(tokens (B, n_tokens), emitted (B,), new_state,
         new_cache)``; per slot, only the first ``emitted`` tokens of its
-        row are real. Jit this with ``donate_argnums`` on ``cache`` so
-        the scan updates the KV rings in place (copy-free decode).
+        row are real. Jit this with ``donate_argnums`` on ``cache`` for a
+        copy-free decode: the token scan carries the donated cache, and
+        each layer scan carries the paged pools (``_layer_scan``), so a
+        token's K/V row is written into the donated buffer in place and
+        the paged kernel reads it there — no copy of the pool follows
+        either loop. Dense rings still ride the layer scan's xs/ys.
         """
         def step(carry, _):
             cache, tok, pos, rem, act, key = carry

@@ -502,11 +502,8 @@ class PagedCache:
             def copy_page(t, src, dst):
                 if isinstance(t, dict) and is_paged_group(t):
                     out = dict(t)
-                    # stack depth from the TABLE (always 2 trailing dims):
-                    # page arrays have a group-dependent trailing rank
-                    # (attention 4, int8 scales / MLA latents 3), so
-                    # deriving it from the pages themselves would index
-                    # the LAYER axis as the page axis for 3-dim groups
+                    # stack depth from the TABLE (always 2 trailing
+                    # dims); every page array is (P+1, bs, ·) under it
                     sdims = t["table"].ndim - 2
                     for dk, _ in _PAGE_PAIRS:
                         if dk not in t:
@@ -560,8 +557,7 @@ class PagedCache:
             def gather_prefix(t, table_rows, pos):
                 if isinstance(t, dict) and is_paged_group(t):
                     out = {}
-                    # table-derived stack depth, as in _copy_fn: page
-                    # arrays have group-dependent trailing rank
+                    # table-derived stack depth, as in _copy_fn
                     sdims = t["table"].ndim - 2
                     for dk, sk in _PAGE_PAIRS:
                         if dk not in t:
@@ -569,7 +565,11 @@ class PagedCache:
                         pages = t[dk]
                         pf = pages.reshape((-1,) + pages.shape[sdims:])
                         pp = table_rows[:, pos // bs]        # (n, H)
-                        g = pf[:, pp, pos % bs]   # (S, n, H, kv, hd)
+                        # (S, n, H, kv·hd), the layer indexed explicitly
+                        # as in insert (a gather sliced over the layers
+                        # makes the TPU compiler relayout the pool)
+                        layer = jnp.arange(pf.shape[0])[:, None, None]
+                        g = pf[layer, pp[None], (pos % bs)[None, None]]
                         out[sk] = g.reshape(pages.shape[:sdims]
                                             + g.shape[1:])
                     return out
@@ -583,9 +583,10 @@ class PagedCache:
 
     def gather_prefix(self, rows: list[int], n_tokens: int) -> Any:
         """Read the first ``n_tokens`` cached positions of ``rows`` out
-        of the paged pool as dense per-group K/V — the attention context
-        a suffix prefill consumes. Pure read (no donation): call BEFORE
-        ``insert`` consumes the tree."""
+        of the paged pool as dense per-group K/V in the pages' token
+        shape (``(…, n, n_tokens, Hkv·hd)`` for attention) — the
+        attention context a suffix prefill consumes. Pure read (no
+        donation): call BEFORE ``insert`` consumes the tree."""
         return self._gather_fn()(self.tree,
                                  jnp.asarray(self._table_rows(rows)),
                                  jnp.arange(n_tokens))
@@ -611,22 +612,33 @@ class PagedCache:
                     pages, s = dst[dk], src[sk]
                     bs = pages.shape[sdims + 1]
                     W = s.shape[sdims + 1]
-                    pos = jnp.arange(W) + offset_
-                    bi = pos // bs
+                    nb = -(-W // bs)
+                    # whole pages from ``offset`` (a block boundary) on:
+                    # the tail past the prompt is garbage either way, and
+                    # a page is one contiguous block of the pool where a
+                    # token row is a strided sublane of its tiles
+                    bi = offset_ // bs + jnp.arange(nb)
                     # offset + bucket padding can run past the table:
-                    # clamp those positions to the scratch sink (jax
-                    # would silently clamp the gather to the LAST table
-                    # entry — a live block — instead)
+                    # clamp those blocks to the scratch sink (jax would
+                    # silently clamp the gather to the LAST table entry
+                    # — a live block — instead)
                     pp = jnp.where(bi[None, :] < nblk,
                                    table_rows[:, jnp.minimum(bi, nblk - 1)],
                                    scratch)
-                    off = jnp.broadcast_to(pos % bs, pp.shape)
                     pf = pages.reshape((-1,) + pages.shape[sdims:])
+                    # the per-request side takes the page's token shape
+                    # ((W, Hkv, hd) -> (W, Hkv·hd)); the pool is not moved
                     sf = s.astype(pages.dtype).reshape(
-                        (-1,) + s.shape[sdims:])
-                    scat = jax.vmap(
-                        lambda pg, sr, pp=pp, off=off:
-                            pg.at[pp, off].set(sr))(pf, sf)
+                        (-1,) + s.shape[sdims:sdims + 2]
+                        + pages.shape[sdims + 2:])
+                    sf = jnp.pad(sf, [(0, 0), (0, 0), (0, nb * bs - W)]
+                                 + [(0, 0)] * (sf.ndim - 3))
+                    sf = sf.reshape(sf.shape[:2] + (nb, bs) + sf.shape[3:])
+                    # the layer indexed explicitly: a scatter batched
+                    # over the layers (vmap) makes the TPU compiler lay
+                    # the whole pool out layer-minor, a copy each way
+                    layer = jnp.arange(pf.shape[0])[:, None, None]
+                    scat = pf.at[layer, pp[None]].set(sf)
                     out[dk] = scat.reshape(pages.shape)
                 return out
 
@@ -655,7 +667,12 @@ class PagedCache:
         bucket-padded prefill garbage goes to the sink, while live
         positions are copied verbatim (the bit-parity guarantee). A
         nonzero ``offset`` shifts the landing positions: the suffix path
-        writes residual K/V behind ``offset`` shared-prefix positions."""
+        writes residual K/V behind ``offset`` shared-prefix positions,
+        a whole number of blocks (hits are full blocks). Pages are
+        written whole, so ``offset`` must be a block boundary."""
+        if offset % self.layout.block_size:
+            raise ValueError(f"offset {offset} is not a multiple of "
+                             f"block_size {self.layout.block_size}")
         self.tree = self._insert_fn()(self.tree, src_cache,
                                       jnp.asarray(rows, jnp.int32),
                                       jnp.asarray(self._table_rows(rows)),

@@ -27,8 +27,9 @@ fused chunk of up to ``chunk_tokens`` decode iterations entirely on
 device (``Model.decode_chunk`` — a ``lax.scan`` with sampling and stop
 conditions in-graph), paying one XLA dispatch and one host transfer per
 chunk instead of per token. The chunk jit **donates the KV cache** (as
-does the admission row-scatter), so decode never copies the cache —
-after a step the previous cache buffers are invalid, which is why the
+does the admission row-scatter), so decode writes the paged pool in
+place (``Model.decode_chunk`` says how) — after a step the previous
+cache buffers are invalid, which is why the
 engine always replaces ``self.cache`` with the returned tree. Chunk
 length defaults to the roofline cost model
 (``core/roofline.decode_chunk_tokens``) and is clamped each step by the

@@ -2,8 +2,9 @@
 
 Nothing runs: the TPU compiler installed with JAX compiles each kernel
 at qwen3-0.6b widths (16 query / 8 kv heads, head_dim 128, max_len 512,
-the prompt buckets the engine serves) for one chip of a ``v5e:2x2``
-topology that is described, not attached. This is what interpret-mode
+the prompt buckets the engine serves), and the paged decode program at
+the benchmark cell's shapes, for one chip of a ``v5e:2x2`` topology
+that is described, not attached. This is what interpret-mode
 tests cannot show: the TPU lowering refuses block shapes that the
 interpreter accepts. The topology is described inside a fixture, never
 at import, and every test that needs it lives in this one file: one
@@ -13,6 +14,7 @@ the worker given this file should.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,13 +84,187 @@ def test_decode_attention_int8_compiles(one_chip):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attention_compiles(one_chip, dtype):
     """The paged default: 2 slots × 512 / 16 = 64 pages (+ scratch) and
-    64 resident rows, block_size 16."""
+    64 resident rows, block_size 16; a 28-layer pool read at a layer
+    index."""
     from repro.kernels.paged_attention import paged_decode_attention
     i32 = jnp.int32
     _compiles_to_kernel(one_chip, paged_decode_attention,
-                        ((64, H, HD), dtype), ((65, 16, HKV, HD), dtype),
-                        ((65, 16, HKV, HD), dtype), ((64, 32), i32),
-                        ((64,), i32))
+                        ((64, H, HD), dtype), ((28, 65, 16, HKV * HD), dtype),
+                        ((28, 65, 16, HKV * HD), dtype), ((64, 32), i32),
+                        ((64,), i32), ((), i32))
+
+
+def test_paged_decode_attention_head_major_compiles(one_chip):
+    """stablelm-1.6b's 32 kv heads of 64: a 64-lane head block breaks
+    the (8, 128) rule, so the kernel reads a head-major copy of the
+    layer's pool."""
+    from repro.kernels.paged_attention import paged_decode_attention
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    _compiles_to_kernel(one_chip, paged_decode_attention,
+                        ((64, 32, 64), bf16), ((24, 65, 16, 32 * 64), bf16),
+                        ((24, 65, 16, 32 * 64), bf16), ((64, 32), i32),
+                        ((64,), i32), ((), i32))
+
+
+def test_paged_decode_attention_int8_compiles(one_chip):
+    from repro.kernels.paged_attention import paged_decode_attention_int8
+    f32, i8, i32 = jnp.float32, jnp.int8, jnp.int32
+    _compiles_to_kernel(one_chip, paged_decode_attention_int8,
+                        ((64, H, HD), f32), ((28, 65, 16, HKV * HD), i8),
+                        ((28, 65, 16, HKV * HD), i8),
+                        ((28, 65, 16, HKV), f32), ((28, 65, 16, HKV), f32),
+                        ((64, 32), i32), ((64,), i32), ((), i32))
+
+
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+                "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8, "u64": 8}
+# ops whose output is no new buffer: it aliases an operand or a carry
+_ALIASING = {"parameter", "tuple", "get-tuple-element", "while", "bitcast"}
+
+
+# in-place updates of their first operand, alone or as a fusion's root
+_IN_PLACE = {"scatter", "dynamic-update-slice"}
+
+
+def _large_outputs(hlo: str, limit: int) -> list[str]:
+    """Instructions of an optimized HLO module, outside fused
+    computations, whose output holds an array of ``limit`` bytes or
+    more; ops that alias their input and in-place updates (a scatter or
+    dynamic-update-slice, or a fusion whose root is one) are left out."""
+    comps, roots, cur = {}, {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%([\w.\-]+)\s.*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+            continue
+        if cur is not None:
+            cur.append(line)
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    inst = re.compile(r"^\s*(ROOT\s+)?%(\S+)\s*=\s*(.*?)\s+([a-z][\w\-]*)\(")
+    for name, lines in comps.items():
+        for line in lines:
+            m = inst.match(line)
+            if m and m.group(1):
+                roots[name] = m.group(4)
+    found = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = inst.match(line)
+            if not m or m.group(4) in _ALIASING | _IN_PLACE:
+                continue
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            if (m.group(4) == "fusion" and called
+                    and roots.get(called.group(1)) in _IN_PLACE):
+                continue
+            for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]",
+                                       m.group(3)):
+                size = _DTYPE_BYTES.get(dt, 0)
+                for d in filter(None, dims.split(",")):
+                    size *= int(d)
+                if size >= limit:
+                    found.append(f"{m.group(4)} %{m.group(2)}: "
+                                 f"{dt}[{dims}]")
+    return found
+
+
+# the qwen3-0.6b.chat cell (bench/configs/qwen3-0.6b.json): 72 rows over
+# 460 pages of 128 tokens plus the scratch page, max_len 2048, bf16
+CELL_ROWS, CELL_MAX_LEN, CELL_BLOCK, CELL_PAGES = 72, 2048, 128, 460
+
+
+def _cell_cache(sharding):
+    """qwen3-0.6b and its paged cache at the cell's shapes, as shape
+    structs on the described chip, and one layer's pool in bytes."""
+    from repro.configs.registry import get_config
+    from repro.models.cache import PagedLayout
+    from repro.models.model import Model
+    model = Model(get_config("qwen3-0.6b"))
+    layout = PagedLayout(CELL_BLOCK, CELL_PAGES)
+    cache = _on(sharding, jax.eval_shape(lambda: model.init_cache(
+        CELL_ROWS, CELL_MAX_LEN, jnp.bfloat16, layout=layout)))
+    pool = cache["stack"]["k_pages"]
+    assert pool.shape == (28, CELL_PAGES + 1, CELL_BLOCK, 8 * 128)
+    return model, layout, cache, pool.size // 28 * pool.dtype.itemsize
+
+
+def _on(sharding, tree):
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+
+
+def _assert_pool_untouched(compiled, layer_pool):
+    assert _large_outputs(compiled.as_text(), layer_pool) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+@pytest.mark.parametrize("n_tokens", [1, 4])
+def test_paged_decode_chunk_updates_pool_in_place(one_chip, monkeypatch,
+                                                  n_tokens):
+    """qwen3-0.6b's decode chunk at the qwen3-0.6b.chat cell's shapes
+    (bench/configs/qwen3-0.6b.json: 28 layers, 72 rows, 460 pages of
+    128 tokens plus the scratch page, bf16, the cache donated), with the
+    Pallas kernel as on the chip. The pools ride the layer scan's carry
+    and the kernel reads them where they lie, so no instruction but the
+    parameters and the in-place token scatter outputs an array of one
+    layer's pool or more, and the temporaries stay far below one pool
+    (they were 7.49 GB while the layer scan sliced and restacked it and
+    the kernel transposed it). One token is the cell's chunk."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_mode", lambda: "tpu")
+    model, _, cache, layer_pool = _cell_cache(one_chip)
+    i32 = jnp.int32
+    params = _on(one_chip, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.bfloat16)))
+    state = _on(one_chip, {
+        "tokens": jax.ShapeDtypeStruct((CELL_ROWS,), i32),
+        "pos": jax.ShapeDtypeStruct((CELL_ROWS,), i32),
+        "remaining": jax.ShapeDtypeStruct((CELL_ROWS,), i32),
+        "active": jax.ShapeDtypeStruct((CELL_ROWS,), jnp.bool_),
+        "key": jax.eval_shape(lambda: jax.random.PRNGKey(0))})
+
+    def decode_chunk(params, cache, state):
+        return model.decode_chunk(params, cache, state, n_tokens,
+                                  max_len=CELL_MAX_LEN)
+    compiled = jax.jit(decode_chunk, donate_argnums=(1,)).lower(
+        params, cache, state).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_pool_untouched(compiled, layer_pool)
+
+
+def test_paged_cache_programs_update_pool_in_place(one_chip):
+    """Every cache program that touches pages, at the same cell shapes:
+    the prefill insert (a 2048-wide dense mini-cache row scattered into
+    its pages), the prefix gather, the copy-on-write page copy, the
+    table scrub and the table write. Each reads or updates the pool in
+    place; a scatter or gather batched over the layers made the TPU
+    compiler relayout the whole pool, one copy each way."""
+    from repro.serving.cache import PagedCache
+    model, layout, cache, layer_pool = _cell_cache(one_chip)
+    one = jax.eval_shape(lambda: model.init_cache(1, CELL_MAX_LEN,
+                                                  jnp.bfloat16))
+    two = jax.eval_shape(lambda: model.init_cache(2, CELL_MAX_LEN,
+                                                  jnp.bfloat16))
+    axes = jax.tree.map(lambda a, b: next(
+        i for i, (x, y) in enumerate(zip(a.shape, b.shape)) if x != y),
+        one, two)
+    cb = PagedCache(cache, CELL_ROWS, layout, CELL_MAX_LEN, axes, {},
+                    prefix_cache=True)
+    nblk = CELL_MAX_LEN // CELL_BLOCK
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    programs = [
+        (cb._insert_fn(), (cache, _on(one_chip, one), i32(1), i32(1, nblk),
+                           i32())),
+        (cb._gather_fn(), (cache, i32(1, nblk), i32(512))),
+        (cb._copy_fn(), (cache, i32(), i32())),
+        (cb._clear_fn(), (cache, i32(1))),
+        (cb._append_fn(), (cache, i32(), i32(1), i32(1))),
+    ]
+    for fn, args in programs:
+        _assert_pool_untouched(fn.lower(*args).compile(), layer_pool)
 
 
 def test_rmsnorm_compiles(one_chip):

@@ -502,3 +502,115 @@ def _each_paged_group_out(tree):
     elif isinstance(tree, dict):
         for v in tree.values():
             yield from _each_paged_group_out(v)
+
+
+# ---------------------------------------------------------------------------
+# the same page-axis programs on attention pages, stored token-major with
+# the kv heads in the lanes: (L, P+1, bs, Hkv·hd)
+# ---------------------------------------------------------------------------
+def _attn_paged_backend(kv_dtype="model"):
+    import dataclasses as dc
+
+    import jax
+
+    from repro.configs.registry import get_config
+    from repro.models.model import Model
+    cfg = dc.replace(get_config("qwen3-0.6b-reduced"),
+                     kv_cache_dtype=kv_dtype)
+    model = Model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    eng = ServingEngine(model, params, EngineConfig(
+        n_slots=2, max_len=64, cache="paged", block_size=16))
+    return eng.cache_backend, cfg
+
+
+def _seed_pages(tree, key):
+    """Every page leaf filled with distinct random values."""
+    import jax
+    import jax.numpy as jnp
+    leaves, tdef = jax.tree_util.tree_flatten_with_path(tree)
+    out = []
+    for i, (path, a) in enumerate(leaves):
+        k = jax.random.fold_in(key, i)
+        if str(path[-1].key).endswith("_pages"):
+            a = (jax.random.randint(k, a.shape, -127, 128).astype(a.dtype)
+                 if a.dtype == jnp.int8
+                 else jax.random.normal(k, a.shape).astype(a.dtype))
+        out.append(a)
+    return jax.tree_util.tree_unflatten(tdef, out)
+
+
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_paged_copy_moves_whole_attention_pages(kv_dtype):
+    """Copy-on-write on attention pages: page ``dst`` of every layer
+    becomes page ``src`` bit for bit (K/V lanes of every kv head, and
+    the int8 scale pages), and no other page changes."""
+    import jax
+    import jax.numpy as jnp
+    cb, cfg = _attn_paged_backend(kv_dtype)
+    group = cb.tree["stack"]
+    assert group["k_pages"].shape[-1] == cfg.n_kv_heads * cfg.head_dim
+    src, dst = 3, 5
+    tree = _seed_pages(cb.tree, jax.random.PRNGKey(8))
+    before = jax.tree.map(np.asarray, tree)
+    copied = cb._copy_fn()(tree, jnp.int32(src), jnp.int32(dst))
+    names = [k for k in group if k.endswith("_pages")]
+    assert len(names) == (4 if kv_dtype == "int8" else 2)
+    for name in names:
+        old, new = before["stack"][name], np.asarray(copied["stack"][name])
+        assert np.array_equal(new[:, dst], old[:, src]), name
+        keep = np.arange(old.shape[1]) != dst
+        assert np.array_equal(new[:, keep], old[:, keep]), name
+
+
+def test_paged_gather_reads_attention_pages():
+    """The prefix gather reads positions through the table rows and
+    returns them in the pages' token shape (L, n, S, Hkv·hd), across a
+    page boundary and for rows that own different pages."""
+    import jax
+    import jax.numpy as jnp
+    cb, cfg = _attn_paged_backend()
+    bs = cb.layout.block_size
+    tree = _seed_pages(cb.tree, jax.random.PRNGKey(9))
+    table_rows = jnp.array([[2, 6, 1, 0], [7, 3, 4, 5]], jnp.int32)
+    pos = jnp.arange(2 * bs - 3, dtype=jnp.int32)
+    got = cb._gather_fn()(tree, table_rows, pos)["stack"]
+    tr, p = np.asarray(table_rows), np.asarray(pos)
+    for name, key in (("k_pages", "k"), ("v_pages", "v")):
+        pages = np.asarray(tree["stack"][name])
+        want = pages[:, tr[:, p // bs], p % bs]
+        assert got[key].shape == (cfg.n_layers, 2, len(p),
+                                  cfg.n_kv_heads * cfg.head_dim)
+        assert np.array_equal(np.asarray(got[key]), want), name
+
+
+def test_paged_insert_then_gather_round_trips_dense_rows():
+    """A dense (L, B, W, Hkv, hd) prefill mini-cache inserted into the
+    pages (the per-request side reshaped to Hkv·hd lanes) reads back
+    from the prefix gather as the same values, one row per request."""
+    import jax
+
+    from repro.models.model import Model
+    cb, cfg = _attn_paged_backend()
+    W = 24                                    # 1.5 pages per row
+    for row in (0, 1):
+        assert cb.alloc(row, W)
+    dense = jax.eval_shape(lambda: Model(cfg).init_cache(2, W))["stack"]
+    keys = jax.random.split(jax.random.PRNGKey(10), 2)
+    src = {"stack": {name: jax.random.normal(k, dense[name].shape)
+                     for k, name in zip(keys, ("k", "v"))}}
+    want = jax.tree.map(np.asarray, src)["stack"]
+    cb.insert(src, [0, 1])
+    got = cb.gather_prefix([0, 1], W)["stack"]
+    for name in ("k", "v"):
+        assert np.array_equal(
+            np.asarray(got[name]),
+            want[name].reshape(want[name].shape[:3] + (-1,))), name
+
+
+def test_paged_insert_refuses_offset_inside_a_block():
+    """The insert writes whole pages from ``offset`` on, so an offset
+    inside a block (which a prefix hit never gives) is refused."""
+    cb, _ = _attn_paged_backend()
+    with pytest.raises(ValueError, match="block_size"):
+        cb.insert({}, [0], offset=cb.layout.block_size // 2)
