@@ -26,16 +26,15 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=20.0)
     args = ap.parse_args(argv)
     from bench import correct, spec
-    from bench.models import dense
     cell = spec.load_cell(args.workload)
     bench_run._compile_cache()
     cfg, mod = cell.config, cell.model
     for seed in (int(s) for s in args.seeds.split(",")):
         res, log, sample = bench_run.run_cell(cell, seed, args.seconds,
                                               False)
-        w = dense.reference_weights(cfg, mod.FLAGS, seed)
-        ref = dense.Reference(cfg, mod.FLAGS, w)
-        ctl = dense.Reference(cfg, mod.FLAGS, w, fp8=True)
+        w = mod.reference_weights(cfg, seed)
+        ref = mod.Reference(cfg, w)
+        ctl = mod.Reference(cfg, w, fp8=True)
         ctrl = correct.logit_gaps(log, sample, ref,
                                   int(cfg["serving"]["max_len"]), control=ctl)
         del ref, ctl, w

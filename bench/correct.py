@@ -11,9 +11,9 @@ the reference's own argmax. The traffic is greedy, so a served token
 that lies far below the best is a wrong token, not a sampled one.
 
 The control reads the same number for the reference computed in fp8
-(``bench.models.dense`` with ``fp8=True``): at each position of the same
-prompts and served tokens, the gap of the token the fp8 forward puts
-first.
+(the model file's ``Reference`` with ``fp8=True``): at each position of
+the same prompts and served tokens, the gap of the token the fp8 forward
+puts first.
 """
 from __future__ import annotations
 

@@ -1,5 +1,6 @@
-"""Dense decoder-only transformers: weights from a seed, the program's
-configuration and parameter tree, and the plain float32 reference.
+"""The dense family: decoder-only transformers with grouped-query
+attention and a gated MLP. Its shapes, the program's configuration and
+parameter tree, its work and the plain float32 reference.
 
 The benchmark makes the weights, not the program: ``init_weights`` draws
 them from the run's seed in a canonical layout (one stacked array per
@@ -8,39 +9,28 @@ that tree into the serving program's parameter tree, and ``Reference``
 is the reference: the published equations in plain ``jax.numpy``, float32
 at ``highest`` matmul precision, one layer at a time, with no kernel,
 cache or batching (``fp8=True`` makes it the control, every matmul's
-operands rounded to float8 e4m3). It imports nothing of the program (``program_config`` and
-``to_program`` touch the program's types only through arguments and a
-local import) and reads nothing the program made: ``reference_weights``
-draws the same canonical tree again from the seed.
+operands rounded to float8 e4m3). It imports nothing of the program
+(``program_config`` and ``to_program`` touch the program's types only
+through arguments and a local import) and reads nothing the program
+made: ``reference_weights`` draws the same canonical tree again from the
+seed.
 
-A model file (``bench/models/<model_type>.py``) states its family's flags
-in ``FLAGS``: the norm kind and whether q and k are normalised per head;
-the rest (tied head, partial rotary, sizes) is read from the config.
+Every function takes the family's ``flags``: the norm kind and whether q
+and k are normalised per head; the rest (tied head, partial rotary,
+sizes) is read from the config. A model file that names a dense model
+(``bench/models/qwen3.py``) binds its flags and exports the contract of
+``bench/spec.py``; the harness never calls this file directly.
 """
 from __future__ import annotations
 
 import math
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-# canonical weight scales: the embedding as Hugging Face initialises it,
-# the projections at 1/sqrt(fan_in) so that activations stay near unit
-# scale through the full depth, norm gains drawn round 1 so that a gain
-# applied to the wrong axis or left out shows in the logits
-EMBED_STD = 0.02
-GAIN_SPREAD = 0.2
-BIAS_STD = 0.05
-
-
-def seed_key(seed: int) -> jax.Array:
-    """A PRNG key from any whole seed, including ones past 32 bits."""
-    if seed < 0:
-        raise ValueError(f"seed must be >= 0, got {seed}")
-    key = jax.random.PRNGKey(seed % 2**31)
-    return jax.random.fold_in(key, seed // 2**31)
+from bench.models.common import draw_tree, mm, seed_key
+from bench.work import DenseWork
 
 
 def dims(cfg: dict) -> dict:
@@ -73,39 +63,12 @@ def _shapes(cfg: dict, flags: dict) -> dict:
     return out
 
 
-def _draw(path: str, shape: tuple, key: jax.Array) -> jax.Array:
-    if path.endswith("scale") or path.endswith("_norm"):
-        return 1.0 + GAIN_SPREAD * (jax.random.uniform(key, shape) - 0.5) * 2
-    if path.endswith("bias"):
-        return BIAS_STD * jax.random.normal(key, shape)
-    if path == "embed":
-        return EMBED_STD * jax.random.normal(key, shape)
-    fan_in = shape[-2]
-    return jax.random.normal(key, shape) / math.sqrt(fan_in)
-
-
 def init_weights(cfg: dict, flags: dict, key: jax.Array,
                  dtype=jnp.bfloat16) -> dict:
-    """The canonical tree, every leaf drawn from ``key`` folded with its
-    path, so that each leaf is the same whatever else the tree holds.
+    """The canonical tree drawn from ``key`` (``common.draw_tree``).
     Call under ``jax.jit`` so that the tree is made on the device in one
     program."""
-    shapes = _shapes(cfg, flags)
-
-    def walk(node, prefix):
-        if isinstance(node, dict):
-            return {k: walk(v, f"{prefix}.{k}" if prefix else k)
-                    for k, v in node.items()}
-        leaf_key = jax.random.fold_in(key, _path_id(prefix))
-        return _draw(prefix, node, leaf_key).astype(dtype)
-    return walk(shapes, "")
-
-
-def _path_id(path: str) -> int:
-    h = 0
-    for ch in path.encode():
-        h = (h * 131 + ch) % 2**31
-    return h
+    return draw_tree(_shapes(cfg, flags), key, dtype)
 
 
 def reference_weights(cfg: dict, flags: dict, seed: int) -> dict:
@@ -113,6 +76,12 @@ def reference_weights(cfg: dict, flags: dict, seed: int) -> dict:
     the bfloat16 values the program was served."""
     w = jax.jit(lambda k: init_weights(cfg, flags, k))(seed_key(seed))
     return jax.tree.map(lambda a: a.astype(jnp.float32), w)
+
+
+def work(cfg: dict) -> DenseWork:
+    """The family's work model: ``bench/work.py``'s dense formulas at this
+    configuration's sizes."""
+    return DenseWork(dims(cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -164,42 +133,9 @@ def to_program(w: dict, cfg: dict, flags: dict) -> dict:
     return p
 
 
-def check_program_tree(params: Any, expected: Any) -> None:
-    """Every leaf of the program's own ``init`` tree is filled, with its
-    shape: a program that changes its layout fails here, not in a
-    comparison."""
-    got = jax.tree_util.tree_flatten_with_path(params)[0]
-    want = jax.tree_util.tree_flatten_with_path(expected)[0]
-    got_s = {jax.tree_util.keystr(p): tuple(a.shape) for p, a in got}
-    want_s = {jax.tree_util.keystr(p): tuple(a.shape) for p, a in want}
-    if got_s != want_s:
-        missing = sorted(set(want_s) - set(got_s))
-        extra = sorted(set(got_s) - set(want_s))
-        wrong = sorted(k for k in set(got_s) & set(want_s)
-                       if got_s[k] != want_s[k])
-        raise ValueError(f"weights do not fit the program's tree: missing "
-                         f"{missing}, extra {extra}, wrong shapes {wrong}")
-
-
 # ---------------------------------------------------------------------------
 # the plain reference
 # ---------------------------------------------------------------------------
-def _fp8(x: jax.Array, axis: int = -1) -> jax.Array:
-    """Round to float8 e4m3 with a scale per slice along ``axis``, as an
-    fp8 deployment stores weights and activations."""
-    amax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
-    scale = jnp.maximum(amax, 1e-30) / 448.0
-    return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
-
-
-def _mm(x: jax.Array, w: jax.Array, fp8: bool) -> jax.Array:
-    """``x @ w``; with ``fp8`` both operands are rounded to float8 first:
-    activations per token, weights per output column."""
-    if fp8:
-        x, w = _fp8(x, -1), _fp8(w, 0)
-    return x @ w
-
-
 def _norm(x, p, kind: str, eps: float):
     if kind == "layernorm":
         mu = x.mean(-1, keepdims=True)
@@ -233,9 +169,9 @@ def _layer(cfg: dict, flags: dict, w: dict, x: jax.Array,
     S = x.shape[0]
     pos = jnp.arange(S)
     h = _norm(x, w["ln1"], flags["norm"], eps)
-    q = _mm(h, w["wq"], fp8).reshape(S, H, hd)
-    k = _mm(h, w["wk"], fp8).reshape(S, Hkv, hd)
-    v = _mm(h, w["wv"], fp8).reshape(S, Hkv, hd)
+    q = mm(h, w["wq"], fp8).reshape(S, H, hd)
+    k = mm(h, w["wk"], fp8).reshape(S, Hkv, hd)
+    v = mm(h, w["wv"], fp8).reshape(S, Hkv, hd)
     if flags["qk_norm"]:
         q = q / jnp.sqrt((q * q).mean(-1, keepdims=True) + eps) * w["q_norm"]
         k = k / jnp.sqrt((k * k).mean(-1, keepdims=True) + eps) * w["k_norm"]
@@ -249,11 +185,11 @@ def _layer(cfg: dict, flags: dict, w: dict, x: jax.Array,
     s = jnp.where(pos[None, :, None] >= pos[None, None, :], s, -jnp.inf)
     a = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("hqs,shk->qhk", a, v).reshape(S, H * hd)
-    x = x + _mm(o, w["wo"], fp8)
+    x = x + mm(o, w["wo"], fp8)
     h = _norm(x, w["ln2"], flags["norm"], eps)
-    g = _mm(h, w["w_gate"], fp8)
-    u = _mm(h, w["w_up"], fp8)
-    return x + _mm(jax.nn.silu(g) * u, w["w_down"], fp8)
+    g = mm(h, w["w_gate"], fp8)
+    u = mm(h, w["w_up"], fp8)
+    return x + mm(jax.nn.silu(g) * u, w["w_down"], fp8)
 
 
 def _head(cfg: dict, flags: dict, w: dict, x: jax.Array, fp8: bool):
@@ -261,7 +197,7 @@ def _head(cfg: dict, flags: dict, w: dict, x: jax.Array, fp8: bool):
                     else "layer_norm_eps"])
     h = _norm(x, w["final_norm"], flags["norm"], eps)
     head = w["embed"].T if "lm_head" not in w else w["lm_head"]
-    return _mm(h, head, fp8)
+    return mm(h, head, fp8)
 
 
 class Reference:

@@ -1,22 +1,25 @@
 """Arithmetic shared by the per-layer readers in ``bench/metrics/``.
 
 Each reader gets a ``Context``: the run's request log, the reduced trace
-(None in a run without one), the configuration's sizes, the device's
-peaks and the number of chips. Work is counted from the request log over
-the traced interval, on the host clock the log is stamped with.
+(None in a run without one), the configuration's work model (its model
+file's ``work(cfg)``), the device's peaks and the number of chips. Work
+is counted from the request log over the traced interval, on the host
+clock the log is stamped with, by the family's own formulas: nothing
+here knows a model family.
 """
 from __future__ import annotations
 
 import dataclasses
+from typing import Callable
 
-from bench import stats, work
+from bench import stats
 
 
 @dataclasses.dataclass
 class Context:
     log: object              # bench.drive.Log
     trace: object | None     # bench.trace.Reduced
-    dims: dict
+    work: object             # the model file's work(cfg)
     peaks: dict
     chips: int
     kv_bytes: int            # bytes per cached K or V element
@@ -39,9 +42,9 @@ def model_flops(ctx: Context) -> int:
     total = 0
     for rec, i in ctx.traced_tokens():
         if i == 0:
-            total += work.prefill_flops(ctx.dims, rec.prompt_len)
+            total += ctx.work.prefill_flops(rec.prompt_len)
         else:
-            total += work.token_flops(ctx.dims, rec.prompt_len + i)
+            total += ctx.work.token_flops(rec.prompt_len + i)
     return total
 
 
@@ -69,29 +72,32 @@ def roofline(ctx: Context, flops: int, data: int, kernel: str
     return 100.0 * least / seconds
 
 
-# op names of the kernels, without their instruction numbers
-PAGED_DECODE = r"paged_decode_attention"
-FLASH_PREFILL = r"flash_attention"
-
-
-def paged_decode_roofline(ctx: Context) -> float | None:
+def decode_roofline(ctx: Context, kernel: str,
+                    work_fn: Callable[[int, int], tuple[int, int]]
+                    ) -> float | None:
+    """``kernel``'s share of its roofline over the traced interval's
+    decoded tokens: ``work_fn(keys, kv_bytes)`` gives the operations and
+    bytes of one token decoded against ``keys`` keys."""
     flops = data = 0
     for rec, i in ctx.traced_tokens():
         if i > 0:
-            f, b = work.decode_attention_work(ctx.dims, rec.prompt_len + i,
-                                              ctx.kv_bytes)
+            f, b = work_fn(rec.prompt_len + i, ctx.kv_bytes)
             flops, data = flops + f, data + b
-    return roofline(ctx, flops, data, PAGED_DECODE)
+    return roofline(ctx, flops, data, kernel)
 
 
-def flash_prefill_roofline(ctx: Context) -> float | None:
+def prefill_roofline(ctx: Context, kernel: str,
+                     work_fn: Callable[[int, int], tuple[int, int]]
+                     ) -> float | None:
+    """``kernel``'s share of its roofline over the traced interval's
+    prefills: ``work_fn(prompt_len, act_bytes)`` gives the operations and
+    bytes of one prompt's prefill."""
     flops = data = 0
     for rec, i in ctx.traced_tokens():
         if i == 0:
-            f, b = work.prefill_attention_work(ctx.dims, rec.prompt_len,
-                                               ctx.act_bytes)
+            f, b = work_fn(rec.prompt_len, ctx.act_bytes)
             flops, data = flops + f, data + b
-    return roofline(ctx, flops, data, FLASH_PREFILL)
+    return roofline(ctx, flops, data, kernel)
 
 
 def idle_share(ctx: Context) -> float | None:

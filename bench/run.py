@@ -69,15 +69,36 @@ def check_devices(chips: int) -> dict:
     return info
 
 
+# keys of a configuration's ``serving`` that are the harness's, not the
+# engine's: the weights' and the cache's types, and the sizing notes
+SERVING_OWN = ("weight_dtype", "kv_dtype", "pool_bytes", "why")
+# engine fields the harness sets itself: the cache's type from
+# ``kv_dtype``, and greedy decoding, which the logit-gap check needs
+ENGINE_SET = ("dtype", "greedy")
+
+
 def _engine_config(cfg: dict):
+    """The engine's configuration from ``serving``: every key that names
+    an ``EngineConfig`` field, the harness's own keys left out, any other
+    key refused, so that a misspelt key fails at set-up and not as a
+    silent default."""
+    import dataclasses
+
     import jax.numpy as jnp
 
     from repro.serving import EngineConfig
     s = cfg["serving"]
-    keys = ("cache", "block_size", "max_len", "max_blocks", "max_seqs",
-            "n_slots", "prefix_cache", "batch_admit")
+    fields = {f.name for f in dataclasses.fields(EngineConfig)}
+    unknown = sorted(k for k in s if k not in SERVING_OWN
+                     and (k not in fields or k in ENGINE_SET))
+    if unknown:
+        raise ValueError(
+            f"unknown serving keys {unknown}: each key is an EngineConfig "
+            f"field the harness does not set itself "
+            f"({sorted(fields - set(ENGINE_SET))}) or one of the harness's "
+            f"own {list(SERVING_OWN)}")
     return EngineConfig(dtype=getattr(jnp, s["kv_dtype"]), greedy=True,
-                        **{k: s[k] for k in keys if k in s})
+                        **{k: v for k, v in s.items() if k in fields})
 
 
 def build(cell, seed: int, containers: int):
@@ -85,16 +106,16 @@ def build(cell, seed: int, containers: int):
     import jax
     import jax.numpy as jnp
 
-    from bench.models import dense
+    from bench.models.common import check_program_tree, seed_key
     from repro.models.model import Model
     from repro.serving.backend import SubmeshBackend, ThreadBackend
     mod, cfg = cell.model, cell.config
-    model = Model(dense.program_config(cfg, mod.FLAGS, cell.config_name))
+    model = Model(mod.program_config(cfg, cell.config_name))
     wdtype = getattr(jnp, cfg["serving"]["weight_dtype"])
-    make = jax.jit(lambda k: dense.to_program(
-        dense.init_weights(cfg, mod.FLAGS, k, wdtype), cfg, mod.FLAGS))
-    params = jax.block_until_ready(make(dense.seed_key(seed)))
-    dense.check_program_tree(params, jax.eval_shape(
+    make = jax.jit(lambda k: mod.to_program(
+        mod.init_weights(cfg, k, wdtype), cfg))
+    params = jax.block_until_ready(make(seed_key(seed)))
+    check_program_tree(params, jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), wdtype)))
     config = _engine_config(cfg)
     if cell.chips == 1 and containers == 1:
@@ -233,7 +254,6 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
 
     from bench import correct, drive, readers, spec, stats, traffic
     from bench import trace as trace_lib
-    from bench.models import dense
     from repro.serving import Router
     device = (check_devices(cell.chips) if need_chip else
               {"platform": jax.devices()[0].platform,
@@ -313,7 +333,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
 
     cfg, mod = cell.config, cell.model
     ctx = readers.Context(
-        log=log, trace=reduced, dims=dense.dims(cfg), peaks=peaks or {},
+        log=log, trace=reduced, work=mod.work(cfg), peaks=peaks or {},
         chips=cell.chips,
         kv_bytes=np.dtype(cfg["serving"]["kv_dtype"]).itemsize,
         act_bytes=np.dtype(cfg["serving"]["weight_dtype"]).itemsize)
@@ -329,8 +349,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
                 metrics[m["name"]] = {"value": e2e[m["name"]],
                                       "unit": m["unit"]}
 
-    ref = dense.Reference(cfg, mod.FLAGS,
-                          dense.reference_weights(cfg, mod.FLAGS, seed))
+    ref = mod.Reference(cfg, mod.reference_weights(cfg, seed))
     gaps = correct.logit_gaps(log, sample, ref,
                               int(cfg["serving"]["max_len"]))
     del ref

@@ -3,9 +3,32 @@
 A cell names a configuration (``bench/configs/<config>.json``) and a
 traffic mix (``bench/traffic/<mix>.json``); each per-layer metric is a
 reader in ``bench/metrics/<metric>.py``; a configuration's
-``model_type`` names its model file in ``bench/models/``. Adding a cell,
-a mix, a metric or a configuration adds files and entries and edits
-nothing here.
+``model_type`` names its model file, ``bench/models/<model_type>.py``.
+Adding a cell, a mix, a metric or a configuration adds files and entries
+and edits nothing here.
+
+A model file is the only place that knows its model family. It exports,
+each taking the configuration dict ``cfg``:
+
+- ``program_config(cfg, name)``: the program's ``ArchConfig``;
+- ``init_weights(cfg, key, dtype)``: the canonical weight tree, drawn
+  from ``key`` (under ``jax.jit``, on the device);
+- ``to_program(w, cfg)``: that tree rearranged into the program's own;
+- ``reference_weights(cfg, seed)``: the canonical tree drawn again from
+  the seed, as float32;
+- ``Reference(cfg, weights, fp8=False)``: an object whose
+  ``.logits(tokens)`` is the plain float32 reference at ``highest``
+  precision, or with ``fp8=True`` the fp8 control;
+- ``work(cfg)``: the family's work model, with ``token_flops(keys)``,
+  ``prefill_flops(prompt_len)``, ``decode_attention_work(keys,
+  kv_bytes)`` and ``prefill_attention_work(prompt_len, act_bytes)``
+  (``bench/work.py``).
+
+The harness (``run.py``, ``calibrate.py``, ``readers.py``) reaches the
+model only through these, and helpers that know no family through
+``bench/models/common.py``. A new family adds one model file, a
+configuration and entries, and edits nothing; a dense model binds the
+flags of ``bench/models/dense.py`` (``qwen3.py``).
 """
 from __future__ import annotations
 

@@ -5,10 +5,15 @@ each generated token at its position. It is never counted from a
 kernel's grid or a padded shape, so a kernel that stops touching padding
 reads a higher share of its roofline, never one above 100%.
 
-Each function takes the configuration's sizes (``bench.models.dense.dims``)
-and the cache's bytes per element.
+Each function takes the dense family's sizes (``bench.models.dense.dims``)
+and the cache's bytes per element. A family's model file hands the
+readers its work model (``work(cfg)``, ``bench/spec.py``): ``DenseWork``
+binds these formulas to one configuration's sizes; a family with other
+attention or experts brings its own.
 """
 from __future__ import annotations
+
+import dataclasses
 
 
 def matmul_params(n: dict) -> int:
@@ -59,3 +64,24 @@ def prefill_attention_work(n: dict, prompt_len: int, act_bytes: int
     flops = 4 * L * H * hd * s * (s + 1) // 2
     data = L * s * (2 * H + 2 * Hkv) * hd * act_bytes
     return flops, data
+
+
+@dataclasses.dataclass(frozen=True)
+class DenseWork:
+    """The dense family's work model, at sizes ``n``: what the per-layer
+    readers ask of every family's ``work(cfg)``."""
+    n: dict
+
+    def token_flops(self, keys: int) -> int:
+        return token_flops(self.n, keys)
+
+    def prefill_flops(self, prompt_len: int) -> int:
+        return prefill_flops(self.n, prompt_len)
+
+    def decode_attention_work(self, keys: int, kv_bytes: int
+                              ) -> tuple[int, int]:
+        return decode_attention_work(self.n, keys, kv_bytes)
+
+    def prefill_attention_work(self, prompt_len: int, act_bytes: int
+                               ) -> tuple[int, int]:
+        return prefill_attention_work(self.n, prompt_len, act_bytes)

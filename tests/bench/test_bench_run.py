@@ -1,10 +1,14 @@
 """A whole run of the harness at test widths on the CPU, past its look
 for a chip: it is correct as served, and not correct with the timed path
-broken underneath or with the fp8 control in the program's place."""
+broken underneath or with the fp8 control in the program's place. A
+family that no file of ``bench/`` names runs through the model-file
+contract alone."""
 from __future__ import annotations
 
 import json
 import pathlib
+import sys
+import types
 
 import jax.numpy as jnp
 import pytest
@@ -99,7 +103,7 @@ def test_the_fp8_control_is_not_correct(cell, monkeypatch):
 
     def control_in_place(log, recs, ref, pad_to, control=None):
         served.append(max(orig(log, recs, ref, pad_to)))
-        fp8 = dense.Reference(ref.cfg, ref.flags, ref.w, fp8=True)
+        fp8 = wide.model.Reference(ref.cfg, ref.w, fp8=True)
         return orig(log, recs, ref, pad_to, control=fp8)
 
     monkeypatch.setattr(correct, "logit_gaps", control_in_place)
@@ -110,6 +114,90 @@ def test_the_fp8_control_is_not_correct(cell, monkeypatch):
         assert gap["limit"] == limit
         assert not res["correct"] and gap["value"] > limit, gap
     assert len(served) == 3 and max(served) <= limit, served
+
+
+CONTRACT = ("program_config", "init_weights", "to_program",
+            "reference_weights", "Reference", "work")
+
+
+def test_a_new_family_runs_through_its_model_file_alone(cell, monkeypatch):
+    """A test-only family, registered as ``bench.models.spy_family`` and
+    named by the configuration's ``model_type``: the dense equations with
+    qwen3's flags behind the contract, every call recorded. The harness
+    reaches the model only through it, and calls into ``dense`` come from
+    the family alone."""
+    import dataclasses
+    from bench.models import qwen3
+    calls, callers = [], set()
+
+    def recorded(name, fn):
+        def call(*args, **kw):
+            calls.append(name)
+            return fn(*args, **kw)
+        return call
+
+    for name in CONTRACT + ("dims",):
+        fn = getattr(dense, name)
+
+        def spy(*args, _fn=fn, **kw):
+            callers.add(sys._getframe(1).f_globals["__name__"])
+            return _fn(*args, **kw)
+        monkeypatch.setattr(dense, name, spy)
+
+    flags = qwen3.FLAGS
+    family = types.ModuleType("bench.models.spy_family")
+    family.program_config = recorded(
+        "program_config",
+        lambda cfg, name: dense.program_config(cfg, flags, name))
+    family.init_weights = recorded(
+        "init_weights",
+        lambda cfg, key, dtype=jnp.bfloat16: dense.init_weights(
+            cfg, flags, key, dtype))
+    family.to_program = recorded(
+        "to_program", lambda w, cfg: dense.to_program(w, cfg, flags))
+    family.reference_weights = recorded(
+        "reference_weights",
+        lambda cfg, seed: dense.reference_weights(cfg, flags, seed))
+    family.Reference = recorded(
+        "Reference",
+        lambda cfg, w, fp8=False: dense.Reference(cfg, flags, w, fp8))
+    family.work = recorded("work", lambda cfg: dense.work(cfg))
+    monkeypatch.setitem(sys.modules, family.__name__, family)
+    spied = dataclasses.replace(
+        cell, config={**cell.config, "model_type": "spy_family"})
+    assert spied.model is family
+
+    res, _, _ = run.run_cell(spied, 2**31 + 17, 2.0, False,
+                             need_chip=False)
+    assert res["correct"], res["checks"]
+    assert set(calls) == set(CONTRACT), calls
+    assert callers == {__name__, dense.__name__}, callers
+
+
+def test_the_engine_gets_every_serving_key_it_has():
+    """The committed configuration builds the engine it always built, and
+    engine keys such as ``chunked`` reach the engine."""
+    from repro.serving import EngineConfig
+    cfg = spec.load_cell(CELL).config
+    s = cfg["serving"]
+    assert run._engine_config(cfg) == EngineConfig(
+        dtype=jnp.bfloat16, greedy=True, cache="paged",
+        block_size=s["block_size"], max_len=s["max_len"],
+        max_blocks=s["max_blocks"], max_seqs=s["max_seqs"],
+        prefix_cache=s["prefix_cache"], batch_admit=s["batch_admit"])
+    more = {**cfg, "serving": {**s, "chunked": False, "chunk_tokens": 4}}
+    got = run._engine_config(more)
+    assert got.chunked is False and got.chunk_tokens == 4
+
+
+@pytest.mark.parametrize("key", ["blok_size", "greedy", "dtype"])
+def test_an_unknown_serving_key_is_refused(key):
+    """A misspelt key, or an engine field the harness sets itself, fails
+    at set-up instead of leaving the engine at a silent default."""
+    cfg = spec.load_cell(CELL).config
+    bad = {**cfg, "serving": {**cfg["serving"], key: 16}}
+    with pytest.raises(ValueError, match=key):
+        run._engine_config(bad)
 
 
 def test_the_sample_holds_the_longest_request():

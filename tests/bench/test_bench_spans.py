@@ -46,7 +46,7 @@ def _step(t, prefill=True, line="python"):
 
 
 def _ctx(events):
-    return readers.Context(log=None, trace=tl.reduce(events), dims={},
+    return readers.Context(log=None, trace=tl.reduce(events), work=None,
                            peaks={}, chips=1, kv_bytes=2, act_bytes=2)
 
 

@@ -18,7 +18,10 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_resolves_its_files(cell):
     c = spec.load_cell(cell)
-    assert c.config["model_type"] and c.model.FLAGS
+    assert c.config["model_type"]
+    for name in ("program_config", "init_weights", "to_program",
+                 "reference_weights", "Reference", "work"):
+        assert callable(getattr(c.model, name)), name
     assert c.mix.classes
     assert any(m["name"] == "setup_s" for m in c.end_to_end)
     assert len(c.end_to_end) >= 2 and c.per_layer
