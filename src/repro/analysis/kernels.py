@@ -280,6 +280,7 @@ def run() -> list[Finding]:
                                                 decode_attention_int8)
     from repro.kernels.flash_attention import flash_attention
     from repro.kernels.mla_decode import mla_decode_ctx
+    from repro.kernels.moe_gmm import moe_expert_gmm
     from repro.kernels.paged_attention import (paged_decode_attention,
                                                paged_decode_attention_int8)
     from repro.kernels.ssd_scan import ssd_scan
@@ -321,6 +322,11 @@ def run() -> list[Finding]:
           _f32(2, 1024, 64),
           jax.ShapeDtypeStruct((2, 1024), jnp.bool_)),
          dict(scale=0.0625, block_s=512, interpret=False), {}, False),
+        # 4 row tiles of 128 over 2 held experts of a 3-layer stack
+        ("moe_expert_gmm", moe_expert_gmm,
+         (_f32(4 * 128, 256), _i32(4), _i32(), _i32(),
+          _f32(L, 2, 256, 256), _f32(L, 2, 256, 256), _f32(L, 2, 256, 256)),
+         dict(act="silu", interpret=False), {0: 1, 1: 4, 2: L - 1}, False),
         ("ssd_scan", ssd_scan,
          (_f32(1, 128, 4, 64), _f32(1, 128, 4), _f32(4),
           _f32(1, 128, 2, 64), _f32(1, 128, 2, 64), _f32(4)),

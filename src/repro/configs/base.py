@@ -14,6 +14,23 @@ ArchType = Literal["dense", "moe", "ssm", "hybrid", "vlm", "audio"]
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling as DeepSeek-V2 states it (``rope_scaling`` of
+    type ``"yarn"`` in its config.json): the rotary frequencies blend
+    from the original to ``1/factor`` of it across the correction range
+    that ``beta_fast``/``beta_slow`` rotations over the original context
+    give, cos/sin are scaled by ``mscale``'s factor over
+    ``mscale_all_dim``'s, and attention's softmax scale by the square of
+    ``mscale_all_dim``'s (models/layers.py)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     """Static architecture description (model-card faithful)."""
 
@@ -33,6 +50,7 @@ class ArchConfig:
     qk_norm: bool = False
     rope_theta: float = 10_000.0
     partial_rotary_factor: float = 1.0
+    rope_scaling: YarnScaling | None = None
     sliding_window: int = 0          # 0 = full attention
     local_global_pattern: int = 0    # k => k local layers per 1 global layer
     attn_logit_softcap: float = 0.0
@@ -50,15 +68,24 @@ class ArchConfig:
     v_head_dim: int = 0
 
     # ---- MoE ----
-    n_experts: int = 0
+    n_experts: int = 0               # routed experts the router scores
     n_experts_per_tok: int = 0
+    # the experts this chip holds under expert parallelism: a count
+    # (0 = all ``n_experts``) and the first one's index. The serving
+    # programs compute only the held experts' part of a layer's output
+    # (models/moe.moe_serve); the absent experts' part lies on other chips
+    experts_held: int = 0
+    first_expert: int = 0
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
     n_shared_experts: int = 0
     moe_d_ff: int = 0
     n_dense_layers: int = 0          # leading dense (non-MoE) layers
     router_aux_loss_coef: float = 0.0
-    # capacity factors: train uses GShard-style drops; eval uses a roomier
-    # buffer (E/K makes eval provably dropless — used by the reduced
-    # test configs so prefill/decode match the full forward exactly)
+    # capacity factors of the full-sequence forward (models/moe.moe_fwd):
+    # train uses GShard-style drops; eval uses a roomier buffer (E/K makes
+    # it provably dropless — the reduced test configs set it so that the
+    # forward matches prefill/decode exactly). The serving programs never
+    # drop: moe_serve computes every assignment to a held expert
     moe_train_cf: float = 1.25
     moe_eval_cf: float = 2.0
     # dispatch groups (0/1 = one global dispatch). Set to the data-axis size
@@ -104,6 +131,11 @@ class ArchConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:
@@ -165,7 +197,7 @@ class ArchConfig:
         if not self.is_moe:
             return self.param_count()
         d = self.d_model
-        routed_all = self.n_experts * 3 * d * self.moe_d_ff
+        routed_all = self.n_held * 3 * d * self.moe_d_ff
         routed_active = self.n_experts_per_tok * 3 * d * self.moe_d_ff
         return self.param_count() - (routed_all - routed_active) * (
             self.n_layers - self.n_dense_layers
@@ -208,7 +240,7 @@ class ArchConfig:
             n += self._attn_params() + self.d_model  # cross-attn + 3rd norm
         if self.is_moe:
             n += d * self.n_experts                               # router
-            n += self.n_experts * self._mlp_params(self.moe_d_ff)
+            n += self.n_held * self._mlp_params(self.moe_d_ff)
             n += self.n_shared_experts * self._mlp_params(self.moe_d_ff)
         else:
             n += self._mlp_params(self.d_ff)

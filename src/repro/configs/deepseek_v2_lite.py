@@ -1,10 +1,13 @@
-"""DeepSeek-V2-Lite-16B [arXiv:2405.04434] — MLA kv_lora=512, 64 routed top-6 + 2 shared.
+"""DeepSeek-V2-Lite [arXiv:2405.04434; hf:deepseek-ai/DeepSeek-V2-Lite
+config.json] — MLA (kv_lora_rank 512, no q-LoRA), YaRN rope, one dense
+layer, then 64 routed experts (top-6 of a softmax, gates not
+renormalised) beside 2 shared experts in every layer.
 
-The assignment bracket mentions "160 routed", which is DeepSeek-V2-full's
-expert count; the 64e/top-6 figures in the same bracket are the Lite ones
-and are what we build (noted in DESIGN.md).
+All 64 routed experts are held: this is the whole model. A chip's share
+of the experts under expert parallelism sets ``experts_held`` and
+``first_expert`` (bench/configs/deepseek-v2-lite.json holds 8 of them).
 """
-from repro.configs.base import ArchConfig
+from repro.configs.base import ArchConfig, YarnScaling
 
 CONFIG = ArchConfig(
     name="deepseek-v2-lite-16b",
@@ -17,6 +20,10 @@ CONFIG = ArchConfig(
     head_dim=192,           # qk_nope 128 + rope 64
     d_ff=10944,             # first (dense) layer FFN
     vocab_size=102_400,
+    rope_scaling=YarnScaling(factor=40.0,
+                             original_max_position_embeddings=4096,
+                             beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                             mscale_all_dim=0.707),
     mla=True,
     kv_lora_rank=512,
     qk_rope_head_dim=64,
@@ -27,6 +34,7 @@ CONFIG = ArchConfig(
     n_shared_experts=2,
     moe_d_ff=1408,
     n_dense_layers=1,
+    norm_topk_prob=False,
     router_aux_loss_coef=0.003,
     moe_dispatch_groups=16,  # shard-local dispatch (§Perf iter 1/4)
 )

@@ -71,12 +71,14 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "window", "softcap", "block_q", "block_k",
-                     "interpret"))
+                     "interpret", "scale"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     softcap: float = 0.0, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False) -> jax.Array:
-    """q: (B, Sq, H, K); k/v: (B, Skv, Hkv, K). Returns (B, Sq, H, K)."""
+                    block_k: int = 128, interpret: bool = False,
+                    scale: float | None = None) -> jax.Array:
+    """q: (B, Sq, H, K); k/v: (B, Skv, Hkv, Kv). Returns (B, Sq, H, Kv).
+    ``scale`` multiplies the scores (default K**-0.5)."""
     B, Sq, H, K = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     Kv = v.shape[3]
@@ -92,7 +94,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vt = jnp.moveaxis(v, 2, 1)
 
     kernel = functools.partial(
-        _attn_kernel, scale=K ** -0.5, causal=causal, window=window,
+        _attn_kernel, scale=K ** -0.5 if scale is None else scale,
+        causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_k=block_k, seq_kv=Skv,
         seq_q=Sq)
     out = pl.pallas_call(

@@ -59,8 +59,10 @@ def _scores(qb, kb, q0, k0, *, scale, softcap, sq, skv, causal, window):
     return jnp.where(m[None, None, None], s, NEG_INF)
 
 
-def _fwd_impl(q, k, v, *, causal, window, softcap, block_q, block_k):
-    """Returns (out, lse). q: (B,Sq,H,K); k/v: (B,Skv,Hkv,K)."""
+def _fwd_impl(q, k, v, *, causal, window, softcap, block_q, block_k,
+              scale=None):
+    """Returns (out, lse). q: (B,Sq,H,K); k/v: (B,Skv,Hkv,K); ``scale``
+    multiplies the scores (default K**-0.5)."""
     B, Sq, H, K = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     Kv = v.shape[3]
@@ -69,7 +71,7 @@ def _fwd_impl(q, k, v, *, causal, window, softcap, block_q, block_k):
     bk = min(block_k, Skv)
     pq = (-Sq) % bq
     pk = (-Skv) % bk
-    scale = K ** -0.5
+    scale = K ** -0.5 if scale is None else scale
 
     qg = jnp.moveaxis(q.reshape(B, Sq, Hkv, G, K), 1, 3)   # (B,Hkv,G,Sq,K)
     kg = jnp.moveaxis(k, 1, 2)                             # (B,Hkv,Skv,K)
@@ -124,7 +126,7 @@ def _fwd_impl(q, k, v, *, causal, window, softcap, block_q, block_k):
 
 
 def _bwd_impl(q, k, v, out, lse, dout, *, causal, window, softcap,
-              block_q, block_k):
+              block_q, block_k, scale=None):
     B, Sq, H, K = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     Kv = v.shape[3]
@@ -133,7 +135,7 @@ def _bwd_impl(q, k, v, out, lse, dout, *, causal, window, softcap,
     bk = min(block_k, Skv)
     pq = (-Sq) % bq
     pk = (-Skv) % bk
-    scale = K ** -0.5
+    scale = K ** -0.5 if scale is None else scale
 
     qg = jnp.moveaxis(q.reshape(B, Sq, Hkv, G, K), 1, 3)
     og = jnp.moveaxis(out.reshape(B, Sq, Hkv, G, Kv), 1, 3)
@@ -233,23 +235,27 @@ def _bwd_impl(q, k, v, out, lse, dout, *, causal, window, softcap,
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, window, softcap, block_q, block_k):
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, window, softcap, block_q, block_k, scale):
     out, _ = _fwd_impl(q, k, v, causal=causal, window=window,
-                       softcap=softcap, block_q=block_q, block_k=block_k)
+                       softcap=softcap, block_q=block_q, block_k=block_k,
+                       scale=scale)
     return out
 
 
-def _flash_fwd(q, k, v, causal, window, softcap, block_q, block_k):
+def _flash_fwd(q, k, v, causal, window, softcap, block_q, block_k, scale):
     out, lse = _fwd_impl(q, k, v, causal=causal, window=window,
-                         softcap=softcap, block_q=block_q, block_k=block_k)
+                         softcap=softcap, block_q=block_q, block_k=block_k,
+                         scale=scale)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, window, softcap, block_q, block_k, res, dout):
+def _flash_bwd(causal, window, softcap, block_q, block_k, scale, res,
+               dout):
     q, k, v, out, lse = res
     return _bwd_impl(q, k, v, out, lse, dout, causal=causal, window=window,
-                     softcap=softcap, block_q=block_q, block_k=block_k)
+                     softcap=softcap, block_q=block_q, block_k=block_k,
+                     scale=scale)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -257,6 +263,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, block_q: int = 512,
-                    block_k: int = 512):
-    """Drop-in for ``ref.flash_attention`` with flash memory behaviour."""
-    return _flash(q, k, v, causal, window, softcap, block_q, block_k)
+                    block_k: int = 512, scale: float | None = None):
+    """Drop-in for ``ref.flash_attention`` with flash memory behaviour;
+    ``scale`` multiplies the scores (default head_dim**-0.5)."""
+    return _flash(q, k, v, causal, window, softcap, block_q, block_k,
+                  scale)

@@ -30,17 +30,19 @@ def _mode() -> str:
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    softcap: float = 0.0):
+                    softcap: float = 0.0, scale: float | None = None):
+    """``scale`` multiplies the scores; None is head_dim**-0.5."""
     mode = _mode()
     if mode == "ref":
         # flash-structured jnp path: same tiles/memory behaviour as the
         # Pallas kernel (flash_ref docstring) — this is what the dry-run
         # lowers, so the roofline describes the kernel we'd actually run.
         return flash_ref.flash_attention(q, k, v, causal=causal,
-                                         window=window, softcap=softcap)
+                                         window=window, softcap=softcap,
+                                         scale=scale)
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap,
-                               interpret=(mode == "interpret"))
+                               interpret=(mode == "interpret"), scale=scale)
 
 
 def decode_attention(q, k, v, valid, *, softcap: float = 0.0,
@@ -111,6 +113,21 @@ def mla_decode_ctx(q_lat, q_rope, ckv, k_rope, valid, *, scale: float):
     from repro.kernels import mla_decode as _mla
     return _mla.mla_decode_ctx(q_lat, q_rope, ckv, k_rope, valid,
                                scale=scale, interpret=(mode == "interpret"))
+
+
+def moe_expert_gmm(xs, tile_expert, n_tiles, layer, w_gate, w_up, w_down,
+                   *, act: str = "silu"):
+    """Grouped SwiGLU over the held experts' sorted row tiles (see
+    kernels/moe_gmm.py); the weights are the layer stack, read at
+    ``layer``."""
+    mode = _mode()
+    if mode == "ref":
+        return ref.moe_expert_gmm(xs, tile_expert, n_tiles, layer, w_gate,
+                                  w_up, w_down, act=act)
+    from repro.kernels import moe_gmm as _gmm
+    return _gmm.moe_expert_gmm(xs, tile_expert, n_tiles, layer, w_gate,
+                               w_up, w_down, act=act,
+                               interpret=(mode == "interpret"))
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-6):

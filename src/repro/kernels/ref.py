@@ -225,6 +225,30 @@ def mla_decode_ctx(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array,
                       ckv.astype(jnp.float32)).astype(q_lat.dtype)
 
 
+def moe_expert_gmm(xs: jax.Array, tile_expert: jax.Array,
+                   n_tiles: jax.Array, layer, w_gate: jax.Array,
+                   w_up: jax.Array, w_down: jax.Array, *,
+                   act: str = "silu") -> jax.Array:
+    """Grouped SwiGLU oracle, shapes as in moe_gmm.moe_expert_gmm: each
+    tile's rows through its expert; tiles past ``n_tiles`` read 0."""
+    M, d = xs.shape
+    n_tile = tile_expert.shape[0]
+    x = xs.reshape(n_tile, M // n_tile, d)
+    def pick(w):      # each tile's expert's weights, at ``layer``
+        return jax.lax.dynamic_index_in_dim(w, layer,
+                                            keepdims=False)[tile_expert]
+    actfn = jax.nn.silu if act == "silu" else jax.nn.gelu
+    g = jnp.einsum("tmd,tdf->tmf", x, pick(w_gate),
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("tmd,tdf->tmf", x, pick(w_up),
+                   preferred_element_type=jnp.float32)
+    h = (actfn(g) * u).astype(xs.dtype)
+    y = jnp.einsum("tmf,tfd->tmd", h, pick(w_down),
+                   preferred_element_type=jnp.float32)
+    y = jnp.where((jnp.arange(n_tile) < n_tiles)[:, None, None], y, 0.0)
+    return y.reshape(M, d).astype(xs.dtype)
+
+
 def _segsum(x: jax.Array) -> jax.Array:
     """Stable segment-sum: out[..., i, j] = sum_{j < l <= i} x[..., l]."""
     Q = x.shape[-1]

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.kernels import ops as kops
 from repro.models.layers import (apply_rope, init_rmsnorm, mesh_axis_sizes,
-                                 rmsnorm_fwd, truncated_normal)
+                                 rmsnorm_fwd, truncated_normal, yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -342,25 +342,53 @@ def attn_suffix_prefill_into_cache(p: dict, cfg: ArchConfig, x: jax.Array,
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek-V2): latent KV cache, absorbed decode
 # ---------------------------------------------------------------------------
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """The scores' scale: (nope + rope dims)**-0.5, times the square of
+    YaRN's ``mscale_all_dim`` factor where the rope is scaled
+    (DeepSeek-V2's ``softmax_scale``)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    s = cfg.rope_scaling
+    if s is not None and s.mscale_all_dim:
+        scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
+
+
+def mla_rope(cfg: ArchConfig, x: jax.Array, positions: jax.Array
+             ) -> jax.Array:
+    """The rotary embedding of MLA's rope dims (YaRN-scaled where the
+    config says so). x: (..., seq, heads, qk_rope_head_dim)."""
+    return apply_rope(x, positions, cfg.rope_theta,
+                      scaling=cfg.rope_scaling)
+
+
+def mla_latents(p: dict, cfg: ArchConfig, x: jax.Array,
+                positions: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """What the latent cache holds for x: (B, S, d): the normalised
+    latent (B, S, kv_lora_rank) and the shared rope key (B, S, dr)."""
+    r = cfg.kv_lora_rank
+    dkv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"])
+    ckv = rmsnorm_fwd(p["kv_norm"], dkv[..., :r], cfg.norm_eps)
+    k_rope = mla_rope(cfg, dkv[..., None, r:], positions)[:, :, 0]
+    return ckv, k_rope
+
+
 def mla_prefill(p: dict, cfg: ArchConfig, x: jax.Array) -> jax.Array:
     B, S, _ = x.shape
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     positions = jnp.arange(S)[None, :]
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-
-    dkv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"])
-    ckv, k_rope = dkv[..., : cfg.kv_lora_rank], dkv[..., cfg.kv_lora_rank:]
-    ckv = rmsnorm_fwd(p["kv_norm"], ckv, cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)  # shared head
+    q_rope = mla_rope(cfg, q_rope, positions)
+    ckv, k_rope = mla_latents(p, cfg, x, positions)
 
     k_nope = jnp.einsum("bsr,rhk->bshk", ckv, p["w_uk"])
     v = jnp.einsum("bsr,rhk->bshk", ckv, p["w_uv"])
     k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope, (B, S, cfg.n_heads, dr))], axis=-1)
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                  (B, S, cfg.n_heads, dr))], axis=-1)
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    out = kops.flash_attention(q_full, k, v, causal=True, window=0)
+    out = kops.flash_attention(q_full, k, v, causal=True, window=0,
+                               scale=mla_softmax_scale(cfg))
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
 
@@ -375,52 +403,49 @@ def mla_decode(p: dict, cfg: ArchConfig, x: jax.Array, cache: dict,
                pos: jax.Array) -> tuple[jax.Array, dict]:
     """Absorbed MLA decode: attention runs in the latent space. pos: (B,)."""
     B = x.shape[0]
-    dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     positions = pos[:, None].astype(jnp.int32)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-
-    dkv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"])
-    ckv_new = rmsnorm_fwd(p["kv_norm"], dkv[..., :r], cfg.norm_eps)
-    k_rope_new = apply_rope(dkv[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
-
-    bidx = jnp.arange(B)
-    if "ckv_pages" in cache:
-        # paged latent cache: scatter the new latent/rope-key into the
-        # block-table page, then gather the logical view and reuse the
-        # dense MLA context kernel — masked (garbage) positions still
-        # contribute an exact 0.0, so this bit-matches the dense path.
-        bs = cache["ckv_pages"].shape[1]
-        table = cache["table"]
-        pidx = table[bidx, pos // bs]
-        off = jnp.mod(pos, bs)
-        ckv_pages = cache["ckv_pages"].at[pidx, off].set(ckv_new[:, 0])
-        kr_pages = cache["k_rope_pages"].at[pidx, off].set(k_rope_new[:, 0])
-        S = table.shape[1] * bs
-        ckv = ckv_pages[table].reshape(B, S, r)
-        k_rope = kr_pages[table].reshape(B, S, dr)
-        valid = jnp.arange(S)[None, :] <= pos[:, None]
-        q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"])[:, 0]
-        ctx_lat = kops.mla_decode_ctx(q_lat, q_rope[:, 0], ckv, k_rope,
-                                      valid,
-                                      scale=(dn + dr) ** -0.5).astype(
-                                          ckv_pages.dtype)
-        out = jnp.einsum("bhr,rhk->bhk", ctx_lat, p["w_uv"])
-        y = jnp.einsum("bhk,hkd->bd", out, p["wo"])[:, None]
-        return y, {"table": table, "ckv_pages": ckv_pages,
-                   "k_rope_pages": kr_pages}
-    ckv = cache["ckv"].at[bidx, pos].set(ckv_new[:, 0])
-    k_rope = cache["k_rope"].at[bidx, pos].set(k_rope_new[:, 0])
-
+    q_rope = mla_rope(cfg, q_rope, positions)
+    ckv_new, k_rope_new = mla_latents(p, cfg, x, positions)
     # absorb W_uk into q: attention runs in the latent space (the Pallas
     # kernel reads each ckv tile once for score AND context — kernels/
     # mla_decode.py; jnp oracle on CPU)
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"])[:, 0]  # (B,H,r)
+    scale = mla_softmax_scale(cfg)
+
+    bidx = jnp.arange(B)
+    if "ckv_pages" in cache:
+        # paged latent cache: the group's pools are the whole layer stack
+        # (L, P+1, bs, ·), carried by the model's layer scan with this
+        # layer's index under "layer" (model._layer_scan). The new latent
+        # and rope key are written in place at [layer, page, off]; the
+        # rows' pages of this layer are then gathered into a dense
+        # (B, nblk·bs, ·) view for the dense MLA context kernel (every
+        # row's whole table: the one copy this path makes; masked
+        # positions contribute an exact 0.0, so it bit-matches the dense
+        # path)
+        layer, table = cache["layer"], cache["table"]
+        bs = cache["ckv_pages"].shape[-2]
+        pidx = table[bidx, pos // bs]
+        off = jnp.mod(pos, bs)
+        ckv_pages = cache["ckv_pages"].at[layer, pidx, off].set(
+            ckv_new[:, 0].astype(cache["ckv_pages"].dtype))
+        kr_pages = cache["k_rope_pages"].at[layer, pidx, off].set(
+            k_rope_new[:, 0].astype(cache["k_rope_pages"].dtype))
+        S = table.shape[1] * bs
+        ckv = ckv_pages[layer, table].reshape(B, S, r)
+        k_rope = kr_pages[layer, table].reshape(B, S, -1)
+        new = {**cache, "ckv_pages": ckv_pages, "k_rope_pages": kr_pages}
+    else:
+        ckv = cache["ckv"].at[bidx, pos].set(ckv_new[:, 0])
+        k_rope = cache["k_rope"].at[bidx, pos].set(k_rope_new[:, 0])
+        new = {"ckv": ckv, "k_rope": k_rope}
     S = ckv.shape[1]
     valid = jnp.arange(S)[None, :] <= pos[:, None]           # (B, S)
     ctx_lat = kops.mla_decode_ctx(q_lat, q_rope[:, 0], ckv, k_rope, valid,
-                                  scale=(dn + dr) ** -0.5).astype(ckv.dtype)
+                                  scale=scale).astype(ckv.dtype)
     out = jnp.einsum("bhr,rhk->bhk", ctx_lat, p["w_uv"])
     y = jnp.einsum("bhk,hkd->bd", out, p["wo"])[:, None]
-    return y, {"ckv": ckv, "k_rope": k_rope}
+    return y, new

@@ -131,7 +131,8 @@ def attn_moe_prefill(p, cfg: ArchConfig, x, cache, *, window: int = 0):
         h, cache = attn.attn_prefill_into_cache(p["attn"], cfg, xin, cache,
                                                 window=window)
     x = x + h
-    mo, _ = moe_lib.moe_fwd(p["moe"], cfg, norm_fwd(cfg, p["ln2"], x), cfg.act)
+    mo, _ = moe_lib.moe_serve(p["moe"], cfg, norm_fwd(cfg, p["ln2"], x),
+                              cfg.act)
     return constrain_batch(x + mo), cache
 
 
@@ -143,20 +144,14 @@ def attn_moe_suffix_prefill(p, cfg: ArchConfig, x, cache, ctx_k, ctx_v,
     h, cache = attn.attn_suffix_prefill_into_cache(
         p["attn"], cfg, xin, cache, ctx_k, ctx_v, offset)
     x = x + h
-    mo, _ = moe_lib.moe_fwd(p["moe"], cfg, norm_fwd(cfg, p["ln2"], x),
-                            cfg.act)
+    mo, _ = moe_lib.moe_serve(p["moe"], cfg, norm_fwd(cfg, p["ln2"], x),
+                              cfg.act)
     return constrain_batch(x + mo), cache
 
 
 def _mla_fill_cache(pa, cfg: ArchConfig, xin, cache):
-    from repro.models.attention import apply_rope
-    from repro.models.layers import rmsnorm_fwd
-    B, S, _ = xin.shape
-    r = cfg.kv_lora_rank
-    positions = jnp.arange(S)[None, :]
-    dkv = jnp.einsum("bsd,dr->bsr", xin, pa["w_dkv"])
-    ckv = rmsnorm_fwd(pa["kv_norm"], dkv[..., :r], cfg.norm_eps)
-    k_rope = apply_rope(dkv[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    S = xin.shape[1]
+    ckv, k_rope = attn.mla_latents(pa, cfg, xin, jnp.arange(S)[None, :])
     L = cache["ckv"].shape[1]
     take = min(L, S)
     new_ckv = cache["ckv"].at[:, :take].set(ckv[:, :take])
@@ -165,14 +160,17 @@ def _mla_fill_cache(pa, cfg: ArchConfig, xin, cache):
 
 
 def attn_moe_decode(p, cfg: ArchConfig, x, cache, pos):
+    """Returns (x, cache, counts): counts (B, 2) are each row's
+    assignments to held experts and those not computed (moe_serve)."""
     xin = norm_fwd(cfg, p["ln1"], x)
     if cfg.mla:
         h, cache = attn.mla_decode(p["attn"], cfg, xin, cache, pos)
     else:
         h, cache = attn.attn_decode(p["attn"], cfg, xin, cache, pos)
     x = x + h
-    mo, _ = moe_lib.moe_fwd(p["moe"], cfg, norm_fwd(cfg, p["ln2"], x), cfg.act)
-    return constrain_batch(x + mo), cache
+    mo, counts = moe_lib.moe_serve(p["moe"], cfg,
+                                   norm_fwd(cfg, p["ln2"], x), cfg.act)
+    return constrain_batch(x + mo), cache, counts
 
 
 # ---------------------------------------------------------------------------

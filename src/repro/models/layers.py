@@ -5,10 +5,12 @@ Pure functional: every layer is an ``init_*`` returning a params dict and a
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig
+from repro.configs.base import ArchConfig, YarnScaling
 
 
 def truncated_normal(key, shape, dtype, scale):
@@ -88,22 +90,57 @@ def layernorm_fwd(p: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
 # ---------------------------------------------------------------------------
 # rotary position embeddings (partial factor + theta per config)
 # ---------------------------------------------------------------------------
-def rope_freqs(head_dim: int, theta: float, partial: float = 1.0) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``: 0.1·mscale·ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float,
+                          s: YarnScaling) -> tuple[int, int]:
+    """The rotary pairs between which YaRN's ramp runs: the pair that
+    turns ``beta_fast`` times over the original context (floor) and the
+    one that turns ``beta_slow`` times (ceil), clamped to [0, dim - 1]."""
+    def pair(rotations):
+        return dim * math.log(s.original_max_position_embeddings / (
+            rotations * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(pair(s.beta_fast)), 0),
+            min(math.ceil(pair(s.beta_slow)), dim - 1))
+
+
+def rope_freqs(head_dim: int, theta: float, partial: float = 1.0,
+               scaling: YarnScaling | None = None) -> jax.Array:
     rot_dim = int(head_dim * partial) // 2 * 2
     # (rot_dim // 2,)
-    return 1.0 / (theta ** (jnp.arange(0, rot_dim, 2,
-                                       dtype=jnp.float32) / rot_dim))
+    inv = 1.0 / (theta ** (jnp.arange(0, rot_dim, 2,
+                                      dtype=jnp.float32) / rot_dim))
+    if scaling is None:
+        return inv
+    # YaRN: pairs below the correction range keep their frequency,
+    # pairs above it are interpolated by 1/factor, a linear ramp between
+    lo, hi = yarn_correction_range(rot_dim, theta, scaling)
+    hi = hi + 0.001 if lo == hi else hi
+    ramp = jnp.clip((jnp.arange(rot_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               partial: float = 1.0) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+               partial: float = 1.0,
+               scaling: YarnScaling | None = None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq). Rotates the
+    two halves of the rotary dims (``rotate_half``); ``scaling`` is
+    YaRN's, which also scales cos and sin by its mscale ratio."""
     head_dim = x.shape[-1]
-    inv = rope_freqs(head_dim, theta, partial)
+    inv = rope_freqs(head_dim, theta, partial, scaling)
     rot_dim = inv.shape[0] * 2
     ang = positions[..., :, None].astype(jnp.float32) * inv  # (..., seq, rot//2)
     cos = jnp.cos(ang)[..., :, None, :]  # broadcast over heads
     sin = jnp.sin(ang)[..., :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x_rot, x_pass = x[..., :rot_dim], x[..., rot_dim:]
     x1, x2 = jnp.split(x_rot.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

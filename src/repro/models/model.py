@@ -70,17 +70,52 @@ def _stacked_init(init_fn, key, n: int):
     return jax.vmap(init_fn)(jax.random.split(key, n))
 
 
-# page pools of a paged attention group (models/cache.py)
-_POOLS = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
+# page pools of a paged attention or latent group (models/cache.py)
+_POOLS = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages",
+          "ckv_pages", "k_rope_pages")
 
 
 def _pool_groups(tree, path=()) -> list[tuple]:
-    """Paths of the paged attention groups in a cache subtree."""
+    """Paths of the paged attention and latent groups in a cache
+    subtree."""
     if not isinstance(tree, dict):
         return []
-    if "k_pages" in tree:
+    if "k_pages" in tree or "ckv_pages" in tree:
         return [path]
     return [q for k, v in tree.items() for q in _pool_groups(v, path + (k,))]
+
+
+def _expert_groups(tree, path=()) -> list[tuple]:
+    """Paths of the MoE layers (a router beside its experts) in a params
+    subtree."""
+    if not isinstance(tree, dict):
+        return []
+    if "experts" in tree and "router" in tree:
+        return [path]
+    return [q for k, v in tree.items()
+            for q in _expert_groups(v, path + (k,))]
+
+
+def _whole_experts(params):
+    """Split a layer stack's params into what a scan slices per layer and
+    the MoE layers' expert stacks, which it should not: the grouped
+    expert kernel reads a layer's experts where they lie in the stack
+    (a slice would be a copy of the layer's slab). Returns the sliced
+    part and ``inject(p, i)``, which puts each whole stack back into a
+    layer's params with the layer's index under ``"layer"``."""
+    paths = _expert_groups(params)
+    whole, xs = {}, params
+    for path in paths:
+        g = _at(params, path)
+        whole[path] = g["experts"]
+        xs = _put(xs, path, {k: v for k, v in g.items() if k != "experts"})
+
+    def inject(p, i):
+        for path in paths:
+            p = _put(p, path, {**_at(p, path), "experts": whole[path],
+                               "layer": i})
+        return p
+    return xs, inject
 
 
 def _at(tree, path):
@@ -108,11 +143,14 @@ def _layer_scan(step, x, params, cache):
     under ``"layer"``; attention writes its token there in place and the
     kernel reads the pool where it lies. In xs/ys the scan would slice
     each layer's pool out and stack it back: two copies of the pool per
-    layer per token. A group that an enclosing scan already carries (the
-    inner stack of a super-block) keeps its flat pool, and its layers
-    are indexed from the ``"layer"`` it was given."""
+    layer per token. The MoE layers' expert stacks are passed whole in
+    the same way (``_whole_experts``). A group that an enclosing scan
+    already carries (the inner stack of a super-block) keeps its flat
+    pool, and its layers are indexed from the ``"layer"`` it was
+    given."""
     paths = _pool_groups(cache)
     n = jax.tree.leaves(params)[0].shape[0]
+    params, inject = _whole_experts(params)
     pools, base, stride, xs_cache = {}, {}, {}, cache
     for path in paths:
         g = _at(cache, path)
@@ -127,6 +165,7 @@ def _layer_scan(step, x, params, cache):
     def body(carry, xs):
         h, pools = carry
         p, c, i = xs
+        p = inject(p, i)
         for path in paths:
             c = _put(c, path, {**_at(c, path), **pools[path],
                                "layer": base[path] + i * stride[path]})
@@ -478,14 +517,24 @@ class Model:
                 x, nd = jax.lax.scan(d_step, x, (params["dense0"],
                                                  cache["dense0"]))
                 new_cache["dense0"] = nd
-            fwd = (blocks.attn_moe_prefill if fam == "moe"
-                   else blocks.attn_mlp_prefill)
+            if fam == "moe":
+                stack, inject = _whole_experts(params["stack"])
 
-            def step(carry, pc):
-                p, c = pc
-                h, nc = fwd(p, cfg, carry, c, window=cfg.sliding_window)
-                return h, nc
-            x, ns = jax.lax.scan(step, x, (params["stack"], cache["stack"]))
+                def m_step(carry, pci):
+                    p, c, i = pci
+                    return blocks.attn_moe_prefill(
+                        inject(p, i), cfg, carry, c,
+                        window=cfg.sliding_window)
+                x, ns = jax.lax.scan(m_step, x, (stack, cache["stack"],
+                                                 jnp.arange(self.n_moe)))
+            else:
+                def step(carry, pc):
+                    p, c = pc
+                    h, nc = blocks.attn_mlp_prefill(
+                        p, cfg, carry, c, window=cfg.sliding_window)
+                    return h, nc
+                x, ns = jax.lax.scan(step, x, (params["stack"],
+                                               cache["stack"]))
             new_cache["stack"] = ns
         elif fam == "gemma":
             def super_step(carry, pc):
@@ -590,7 +639,16 @@ class Model:
                     pos: jax.Array) -> tuple[jax.Array, Cache]:
         """tokens: (B, 1); pos: (B,) current absolute positions.
         Returns (logits (B, V), new cache)."""
+        logits, cache, _ = self._decode_step(params, tokens, cache, pos)
+        return logits, cache
+
+    def _decode_step(self, params: Params, tokens: jax.Array, cache: Cache,
+                     pos: jax.Array):
+        """``decode_step`` plus, for MoE families, each row's expert
+        counts (B, 2) over its layers: assignments to held experts and
+        those not computed (``moe.moe_serve``); None for the others."""
         cfg, fam = self.cfg, self.fam
+        counts = None
         x = embed_fwd(params["embed"], tokens)
         if cfg.pos_embed == "learned":
             x = x + _sinusoid(pos[:, None], cfg.d_model).astype(x.dtype)
@@ -599,7 +657,7 @@ class Model:
             x, ns = _layer_scan(
                 lambda h, p, c: blocks.cross_decode(p, cfg, h, c, pos), x,
                 params["stack"], cache["stack"])
-            return self._head(params, x[:, -1]), {"stack": ns}
+            return self._head(params, x[:, -1]), {"stack": ns}, counts
 
         def attn_mlp(h, p, c):
             return blocks.attn_mlp_decode(p, cfg, h, c, pos)
@@ -611,11 +669,19 @@ class Model:
             if fam == "moe" and self.n_dense:
                 x, new_cache["dense0"] = _layer_scan(
                     attn_mlp, x, params["dense0"], cache["dense0"])
-            fwd = (blocks.attn_moe_decode if fam == "moe"
-                   else blocks.attn_mlp_decode)
-            x, new_cache["stack"] = _layer_scan(
-                lambda h, p, c: fwd(p, cfg, h, c, pos), x, params["stack"],
-                cache["stack"])
+            if fam == "moe":
+                def moe(hn, p, c):
+                    h, n = hn
+                    h, c, cnt = blocks.attn_moe_decode(p, cfg, h, c, pos)
+                    return (h, n + cnt.reshape(n.shape)), c
+                (x, counts), new_cache["stack"] = _layer_scan(
+                    moe, (x, jnp.zeros((x.shape[0], 2), jnp.int32)),
+                    params["stack"], cache["stack"])
+            else:
+                x, new_cache["stack"] = _layer_scan(
+                    lambda h, p, c: blocks.attn_mlp_decode(p, cfg, h, c,
+                                                           pos),
+                    x, params["stack"], cache["stack"])
         elif fam == "gemma":
             def super_step(h, p, c):
                 h, nl = _layer_scan(attn_mlp, h, p["local"], c["local"])
@@ -646,7 +712,7 @@ class Model:
                                                   cache["rem"])
         else:
             raise ValueError(fam)
-        return self._head(params, x[:, -1]), new_cache
+        return self._head(params, x[:, -1]), new_cache, counts
 
     # ------------------------------------------------------------------
     # fused multi-token decode
@@ -677,17 +743,21 @@ class Model:
 
         Returns ``(tokens (B, n_tokens), emitted (B,), new_state,
         new_cache)``; per slot, only the first ``emitted`` tokens of its
-        row are real. Jit this with ``donate_argnums`` on ``cache`` for a
+        row are real. For MoE families ``new_state["moe_counts"]`` (2,)
+        sums the active rows' expert counts over the chunk
+        (``_decode_step``). Jit this with ``donate_argnums`` on ``cache`` for a
         copy-free decode: the token scan carries the donated cache, and
         each layer scan carries the paged pools (``_layer_scan``), so a
         token's K/V row is written into the donated buffer in place and
         the paged kernel reads it there — no copy of the pool follows
         either loop. Dense rings still ride the layer scan's xs/ys.
         """
+        moe = self.fam == "moe"
+
         def step(carry, _):
-            cache, tok, pos, rem, act, key = carry
-            logits, cache = self.decode_step(params, tok[:, None], cache,
-                                             pos)
+            cache, tok, pos, rem, act, key = carry[:6]
+            logits, cache, counts = self._decode_step(params, tok[:, None],
+                                                      cache, pos)
             if greedy:
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             else:
@@ -697,13 +767,22 @@ class Model:
             pos = jnp.where(act, pos + 1, pos)
             rem = jnp.where(act, rem - 1, rem)
             nact = act & (rem > 0) & (pos < max_len - 1)
-            return (cache, nxt, pos, rem, nact, key), (nxt, act)
+            out = (cache, nxt, pos, rem, nact, key)
+            if moe:   # the active rows' expert counts, summed
+                out += (carry[6] + jnp.sum(
+                    jnp.where(act[:, None], counts, 0), axis=0),)
+            return out, (nxt, act)
 
         carry = (cache, state["tokens"], state["pos"], state["remaining"],
                  state["active"], state["key"])
-        (cache, tok, pos, rem, act, key), (toks, emits) = jax.lax.scan(
-            step, carry, None, length=n_tokens)
+        if moe:
+            carry += (jnp.zeros((2,), jnp.int32),)
+        carry, (toks, emits) = jax.lax.scan(step, carry, None,
+                                            length=n_tokens)
+        cache, tok, pos, rem, act, key = carry[:6]
         new_state = {"tokens": tok, "pos": pos, "remaining": rem,
                      "active": act, "key": key}
+        if moe:
+            new_state["moe_counts"] = carry[6]
         return (jnp.swapaxes(toks, 0, 1),
                 jnp.sum(emits.astype(jnp.int32), axis=0), new_state, cache)

@@ -1,4 +1,6 @@
-"""Mixture-of-Experts: top-k router + capacity-bounded scatter dispatch.
+"""Mixture-of-Experts: top-k router + capacity-bounded scatter dispatch
+for the full-sequence forward, and a dropless grouped dispatch over the
+experts a chip holds for the serving programs (``moe_serve``).
 
 Dispatch is position-in-expert scatter (cumsum over the one-hot expert
 assignment), not the GShard dense one-hot einsum: the scatter adds zero
@@ -10,6 +12,14 @@ router aux loss (Switch-style load balancing) is returned for training.
 Under pjit the (E, C, d) buffers shard over the "model" axis — GSPMD emits
 the all-to-all pair around the expert matmuls. A shard_map variant with
 explicit collectives is a §Perf hillclimb, not the baseline.
+
+``moe_serve`` is what prefill and decode run: the router scores all
+``n_experts`` in float32, and only the assignments to the experts this
+chip holds (``cfg.experts_held`` from ``cfg.first_expert``) are computed,
+sorted by expert into row tiles and run through one grouped SwiGLU
+matmul (``kernels/moe_gmm.py``), so the work follows the assignments and
+none is dropped. What the experts held elsewhere would add is not
+computed here: under expert parallelism it comes from their chips.
 """
 from __future__ import annotations
 
@@ -19,8 +29,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
+from repro.kernels import ops as kops
 from repro.models.layers import (constrain, init_mlp, mesh_axis_sizes,
                                  mlp_fwd, truncated_normal)
+
+# rows of one tile of the grouped expert matmul: each held expert's
+# assignments are padded to a whole number of tiles
+TILE_ROWS = 128
 
 
 def _mesh_info():
@@ -40,15 +55,88 @@ def init_moe(key, cfg: ArchConfig, dtype) -> dict:
     p = {
         "router": truncated_normal(ks[0], (d, cfg.n_experts), jnp.float32,
                                    d ** -0.5),
-        # experts stacked on a leading E axis
+        # the held experts stacked on a leading axis
         "experts": jax.vmap(
             lambda k: init_mlp(k, d, cfg.moe_d_ff, dtype))(
-                jax.random.split(ks[1], cfg.n_experts)),
+                jax.random.split(ks[1], cfg.n_held)),
     }
     if cfg.n_shared_experts:
         p["shared"] = init_mlp(ks[2], d,
                                cfg.moe_d_ff * cfg.n_shared_experts, dtype)
     return p
+
+
+def _route(p: dict, cfg: ArchConfig, xt: jax.Array):
+    """Top-k of the float32 softmax over every routed expert: gates
+    (T, K), expert indices (T, K) and the probabilities (T, E). The
+    gates are renormalised to sum 1 only where ``norm_topk_prob``."""
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)                    # (T, E)
+    gate_vals, expert_idx = jax.lax.top_k(probs, cfg.n_experts_per_tok)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.clip(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    return gate_vals, expert_idx, probs
+
+
+def moe_serve(p: dict, cfg: ArchConfig, x: jax.Array, act: str = "silu"
+              ) -> tuple[jax.Array, jax.Array]:
+    """The serving programs' MoE layer, dropless over the held experts.
+    x: (B, S, d) -> (out (B, S, d), counts (B·S, 2) int32): per token,
+    its assignments to held experts and how many of those were not
+    computed (0 by construction: the tiles cover every assignment).
+
+    Each held expert's assignments are sorted into consecutive tiles of
+    ``TILE_ROWS`` rows; the grouped matmul runs the tiles in use and
+    skips the rest. ``p["experts"]`` is one layer's ``(E_held, …)``
+    weights, or the whole layer stack ``(L, E_held, …)`` with this
+    layer's index under ``p["layer"]`` (model._layer_scan passes the
+    stack whole, so that the kernel reads the experts where they lie)."""
+    B, S, d = x.shape
+    T = B * S
+    K, n, tm = cfg.n_experts_per_tok, cfg.n_held, TILE_ROWS
+    xt = x.reshape(T, d)
+    gate_vals, expert_idx, _ = _route(p, cfg, xt)
+    local = (expert_idx - cfg.first_expert).reshape(T * K)
+    held = (local >= 0) & (local < n)
+    e = jnp.where(held, local, n)                    # n: held elsewhere
+    onehot = jax.nn.one_hot(e, n + 1, dtype=jnp.int32)      # (TK, n+1)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0) - onehot,
+                               e[:, None], axis=1)[:, 0]
+    tiles = -(-jnp.sum(onehot[:, :n], axis=0) // tm)         # (n,)
+    tile_end = jnp.cumsum(tiles)
+    n_tiles = tile_end[-1]
+    # a token sends at most min(K, n) assignments here; each expert's
+    # last tile may be partly padding
+    max_tiles = -(-T * min(K, n) // tm) + n
+    first_row = (tile_end - tiles)[jnp.minimum(e, n - 1)] * tm
+    row = jnp.where(held, first_row + rank, max_tiles * tm)
+    xs = jnp.zeros((max_tiles * tm, d), x.dtype).at[row].set(
+        jnp.repeat(xt, K, axis=0), mode="drop")
+    t = jnp.arange(max_tiles)
+    tile_expert = jnp.minimum(
+        jnp.sum(tile_end[None, :] <= t[:, None], axis=1), n - 1)
+    experts = p["experts"]
+    layer = p.get("layer")
+    if layer is None:
+        experts, layer = jax.tree.map(lambda a: a[None], experts), 0
+    y = kops.moe_expert_gmm(xs, tile_expert.astype(jnp.int32),
+                            n_tiles.astype(jnp.int32), layer,
+                            experts["w_gate"], experts["w_up"],
+                            experts["w_down"], act=act)
+    done = held & (row < max_tiles * tm)
+    rows = jnp.where(done[:, None],
+                     y[jnp.minimum(row, max_tiles * tm - 1)], 0)
+    out = jnp.sum((rows.astype(jnp.float32)
+                   * gate_vals.reshape(T * K, 1)).reshape(T, K, d), axis=1)
+    out = out.astype(x.dtype)
+    if cfg.n_shared_experts:
+        out = out + mlp_fwd(p["shared"], xt, act)
+    counts = jnp.stack([held.reshape(T, K).sum(1),
+                        (held & ~done).reshape(T, K).sum(1)],
+                       axis=1).astype(jnp.int32)
+    return out.reshape(B, S, d), counts
 
 
 def _capacity(n_tokens: int, cfg: ArchConfig, train: bool) -> int:
@@ -152,16 +240,14 @@ def moe_fwd(p: dict, cfg: ArchConfig, x: jax.Array,
     the standard per-device-capacity semantics of production MoE stacks.
     With G=1 this is one global dispatch.
     """
+    if cfg.n_held != cfg.n_experts:
+        raise ValueError("the capacity dispatch runs every expert; a "
+                         "chip's share of them is served by moe_serve")
     B, S, d = x.shape
     T = B * S
     E, K = cfg.n_experts, cfg.n_experts_per_tok
     xt = x.reshape(T, d)
-
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)                    # (T, E)
-    gate_vals, expert_idx = jax.lax.top_k(probs, K)            # (T, K)
-    gate_vals = gate_vals / jnp.clip(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)      # renormalise
+    gate_vals, expert_idx, probs = _route(p, cfg, xt)
 
     # group count follows the ambient mesh (pod×data shards) so dispatch is
     # per-device on ANY mesh; the config knob covers the no-mesh case
@@ -244,11 +330,7 @@ def moe_fwd_ref(p: dict, cfg: ArchConfig, x: jax.Array,
     """Dense (all-experts) oracle used by tests; no capacity drops."""
     B, S, d = x.shape
     xt = x.reshape(-1, d)
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, cfg.n_experts_per_tok)
-    gate_vals = gate_vals / jnp.clip(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    gate_vals, expert_idx, _ = _route(p, cfg, xt)
     all_out = jax.vmap(lambda pe: mlp_fwd(pe, xt, act))(p["experts"])  # (E,T,d)
     mask = jax.nn.one_hot(expert_idx, cfg.n_experts)           # (T,K,E)
     combine = jnp.einsum("tke,tk->te", mask, gate_vals)

@@ -363,6 +363,11 @@ class ServingEngine:
         self.prefix_hit_tokens_total = 0  # positions served from hits
         self.busy_s = 0.0             # wall time spent inside step()
         self.peak_active = 0          # max concurrently active rows seen
+        # MoE families: decoded tokens' assignments to the experts this
+        # chip holds, and those of them not computed (0: moe_serve is
+        # dropless), summed over layers and chunks
+        self.moe_held_assignments = 0
+        self.moe_dropped_assignments = 0
         self.budget_exhausted = False  # last run() hit max_steps with work
 
     @property
@@ -808,7 +813,11 @@ class ServingEngine:
                 self.params, self.cache, state)
             self._key = state["key"]
         with TraceAnnotation("engine.readback"):
-            block, emitted = jax.device_get((block, emitted))
+            block, emitted, moe = jax.device_get(
+                (block, emitted, state.get("moe_counts")))
+        if moe is not None:
+            self.moe_held_assignments += int(moe[0])
+            self.moe_dropped_assignments += int(moe[1])
         now = time.perf_counter()
         with TraceAnnotation("engine.emit"):
             for i in active:

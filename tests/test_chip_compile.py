@@ -293,3 +293,131 @@ def test_ssd_scan_compiles(one_chip):
         one_chip, lambda *a: ssd_scan(*a, chunk=256),
         ((1, 512, 80, 64), f32), ((1, 512, 80), f32), ((80,), f32),
         ((1, 512, 1, 128), f32), ((1, 512, 1, 128), f32), ((80,), f32))
+
+
+# the deepseek-v2-lite.long-answers cell (bench/configs/deepseek-v2-lite.json)
+def _deepseek_cell(sharding):
+    """deepseek-v2-lite at its published widths with this chip's 8 held
+    experts, its paged latent cache at the cell's shapes, as shape
+    structs on the described chip, and the serving settings."""
+    import json
+    import pathlib
+    import sys
+    root = pathlib.Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root))
+    from bench.models import deepseek_v2
+    from repro.models.cache import PagedLayout
+    from repro.models.model import Model
+    cfg = json.loads((root / "bench" / "configs" /
+                      "deepseek-v2-lite.json").read_text())
+    s = cfg["serving"]
+    model = Model(deepseek_v2.program_config(cfg, "deepseek-v2-lite"))
+    params = _on(sharding, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.bfloat16)))
+    cache = _on(sharding, jax.eval_shape(lambda: model.init_cache(
+        s["max_seqs"], s["max_len"], jnp.bfloat16,
+        layout=PagedLayout(s["block_size"], s["max_blocks"]))))
+    return model, params, cache, s
+
+
+def _slabs(model) -> set[str]:
+    """Shapes of one layer's held expert weights, as HLO prints them."""
+    c = model.cfg
+    n, d, f = c.n_held, c.d_model, c.moe_d_ff
+    return {f"[{a}]" for a in (f"{n},{d},{f}", f"{n},{f},{d}",
+                               f"1,{n},{d},{f}", f"1,{n},{f},{d}")}
+
+
+def _no_expert_slab(text: str, model) -> None:
+    """No instruction outputs one layer's held experts (a slice of the
+    stack): the grouped matmul reads the whole stack where it lies."""
+    slabs = _slabs(model)
+    bad = [line.strip()[:160] for line in text.splitlines()
+           if " = " in line and any(f"bf16{s}" in line.split(" = ", 1)[1]
+                                    .split(" ", 1)[0] for s in slabs)]
+    assert bad == []
+
+
+def test_deepseek_decode_chunk_reads_pools_and_experts_in_place(
+        one_chip, monkeypatch):
+    """deepseek-v2-lite's decode chunk at the long-answers cell's shapes
+    (256 rows, 1878 pages of 128 tokens plus scratch, bf16, the cache
+    donated), Pallas kernels as on the chip. The latent pools ride the
+    layer scan's carry and each layer's held experts are read where they
+    lie in the stack: no instruction but the parameters, the in-place
+    token scatters and the dense latent gather outputs a layer's latent
+    pool or more. That gather (``ckv_pages[layer, table]``, every row's
+    whole table: (rows·nblk, bs, kv_lora_rank), one in the dense layer's
+    scan and one in the MoE stack's) is the one known exception, named
+    here by op and size; a paged latent kernel would end it. The
+    temporaries stay under 1 GB (0.69 GB when written)."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_mode", lambda: "tpu")
+    model, params, cache, s = _deepseek_cell(one_chip)
+    rows, i32 = s["max_seqs"], jnp.int32
+    state = _on(one_chip, {
+        "tokens": jax.ShapeDtypeStruct((rows,), i32),
+        "pos": jax.ShapeDtypeStruct((rows,), i32),
+        "remaining": jax.ShapeDtypeStruct((rows,), i32),
+        "active": jax.ShapeDtypeStruct((rows,), jnp.bool_),
+        "key": jax.eval_shape(lambda: jax.random.PRNGKey(0))})
+
+    def decode_chunk(params, cache, state):
+        return model.decode_chunk(params, cache, state, 1,
+                                  max_len=s["max_len"])
+    compiled = jax.jit(decode_chunk, donate_argnums=(1,)).lower(
+        params, cache, state).compile()
+    text = compiled.as_text()
+    for kernel in ("mla_decode_ctx", "moe_expert_gmm"):
+        assert re.search(rf"%{kernel}[.\d]* = .*tpu_custom_call", text)
+    pool = cache["stack"]["ckv_pages"]
+    layer_pool = pool.size // pool.shape[0] * pool.dtype.itemsize
+    nblk = s["max_len"] // s["block_size"]
+    gather = (f"bf16[{rows * nblk},{s['block_size']},"
+              f"{model.cfg.kv_lora_rank}]")
+    found = _large_outputs(text, layer_pool)
+    assert len(found) == 2 and all(
+        f.startswith("fusion ") and f.endswith(gather) for f in found), found
+    for f in found:
+        name = f.split("%", 1)[1].split(":", 1)[0]
+        line = next(x for x in text.splitlines()
+                    if x.strip().startswith(f"%{name} = "))
+        assert re.search(r'op_name="[^"]*/gather"', line), line[:200]
+    _no_expert_slab(text, model)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_deepseek_prefill_reads_experts_in_place(one_chip, monkeypatch):
+    """The engine's prefill program at the cell's widest bucket (512):
+    the held experts are read where they lie, and its temporaries stay
+    under 0.5 GB."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_mode", lambda: "tpu")
+    model, params, _, s = _deepseek_cell(one_chip)
+
+    def prefill(params, batch, idx):
+        return model.prefill(params, batch,
+                             model.init_cache(1, s["max_len"]),
+                             logits_at=idx)
+    compiled = jax.jit(prefill).lower(
+        params,
+        _on(one_chip, {"tokens": jax.ShapeDtypeStruct((1, 512), jnp.int32)}),
+        _on(one_chip, jax.ShapeDtypeStruct((1,), jnp.int32))).compile()
+    text = compiled.as_text()
+    for kernel in ("flash_attention", "moe_expert_gmm"):
+        assert re.search(rf"%{kernel}[.\d]* = .*tpu_custom_call", text)
+    _no_expert_slab(text, model)
+    assert compiled.memory_analysis().temp_size_in_bytes < 5e8
+
+
+def test_moe_expert_gmm_compiles(one_chip):
+    """The grouped matmul at the cell's decode shape: 256 rows' top-6
+    assignments in tiles of 128 (12 tiles, plus one of padding per held
+    expert), over the 26-layer stack of 8 held experts of 2048 × 1408."""
+    from repro.kernels.moe_gmm import moe_expert_gmm
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    _compiles_to_kernel(one_chip, moe_expert_gmm, ((20 * 128, 2048), bf16),
+                        ((20,), i32), ((), i32), ((), i32),
+                        ((26, 8, 2048, 1408), bf16),
+                        ((26, 8, 2048, 1408), bf16),
+                        ((26, 8, 1408, 2048), bf16))
